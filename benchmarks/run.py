@@ -87,6 +87,9 @@ def main(argv=None) -> None:
         sys.modules["benchmarks.common"].COLLECT = args.collect
         sys.modules["benchmarks.common"].TRACE = args.trace
     from benchmarks.common import COLLECT, FULL, SEEDS, SMOKE, TRACE, Rows
+    from repro.utils.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
 
     only = os.environ.get("BENCH_ONLY")
     selected = MODULES

@@ -13,7 +13,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-LANES = 128
+from repro.kernels.tiling import LANES, SCALAR_ROW_SPEC, scalar_row
+
 ROW_TILE = 8  # one (8, 128) VREG per block step
 
 
@@ -32,7 +33,7 @@ def _mix_kernel(flow_ref, ev_ref, salt_ref, nports_ref, out_ref):
     x = x ^ (x >> 15)
     x = x * jnp.uint32(0x846CA68B)
     x = x ^ (x >> 16)
-    nports = nports_ref[0].astype(jnp.uint32)
+    nports = nports_ref[:, 0:1].astype(jnp.uint32)  # (1, 1)
     out_ref[...] = (x % nports).astype(jnp.int32)
 
 
@@ -43,7 +44,7 @@ def ecmp_hash_pallas(
     salt: jax.Array,
     nports: jax.Array,  # () int32
     *,
-    interpret: bool = True,
+    interpret: bool,
 ) -> jax.Array:
     R = flow.shape[0]
     assert flow.shape[1] == LANES and flow.shape == ev.shape == salt.shape
@@ -52,8 +53,8 @@ def ecmp_hash_pallas(
     return pl.pallas_call(
         _mix_kernel,
         grid=grid,
-        in_specs=[spec, spec, spec, pl.BlockSpec((1,), lambda i: (0,))],
+        in_specs=[spec, spec, spec, SCALAR_ROW_SPEC],
         out_specs=spec,
         out_shape=jax.ShapeDtypeStruct((R, LANES), jnp.int32),
         interpret=interpret,
-    )(flow, ev, salt, nports.reshape(1))
+    )(flow, ev, salt, scalar_row(nports))

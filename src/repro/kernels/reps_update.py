@@ -21,6 +21,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels.tiling import SCALAR_ROW_SPEC, scalar_row
+
 CONN_TILE = 128
 BUF = 8  # paper buffer depth
 
@@ -33,15 +35,14 @@ def _reps_tick_kernel(
     ack_mask_ref, ack_ev_ref, ack_ecn_ref, timeout_mask_ref, send_mask_ref,
     rand_ev_ref,
     # scalars
-    params_ref,  # (3,): [now, num_pkts_bdp, freezing_timeout]
+    params_ref,  # (1, 128) scalar row: [now, num_pkts_bdp, freezing_timeout]
     # outputs
     o_buf_ev_ref, o_buf_valid_ref, o_head_ref, o_num_valid_ref,
     o_explore_ref, o_freezing_ref, o_exit_freeze_ref, o_n_cached_ref,
     o_ev_ref,
 ):
-    now = params_ref[0]
-    bdp = params_ref[1]
-    freeze_to = params_ref[2]
+    params = params_ref[...]
+    now, bdp, freeze_to = params[:, 0:1], params[:, 1:2], params[:, 2:3]
 
     buf_ev = buf_ev_ref[...]
     buf_valid = buf_valid_ref[...]  # int32 0/1
@@ -109,7 +110,7 @@ def _reps_tick_kernel(
 def reps_tick_pallas(
     buf_ev, buf_valid, head, num_valid, explore, freezing, exit_freeze,
     n_cached, ack_mask, ack_ev, ack_ecn, timeout_mask, send_mask, rand_ev,
-    now, num_pkts_bdp, freezing_timeout, *, interpret: bool = True,
+    now, num_pkts_bdp, freezing_timeout, *, interpret: bool,
 ):
     """All per-conn inputs are (N,) int32 (masks 0/1); buffers (N, 8) int32.
 
@@ -118,18 +119,10 @@ def reps_tick_pallas(
     N = buf_ev.shape[0]
     assert buf_ev.shape == (N, BUF)
     col = lambda x: x.reshape(N, 1).astype(jnp.int32)
-    params = jnp.stack(
-        [
-            jnp.asarray(now, jnp.int32),
-            jnp.asarray(num_pkts_bdp, jnp.int32),
-            jnp.asarray(freezing_timeout, jnp.int32),
-        ]
-    )
 
     grid = (pl.cdiv(N, CONN_TILE),)
     buf_spec = pl.BlockSpec((CONN_TILE, BUF), lambda i: (i, 0))
     col_spec = pl.BlockSpec((CONN_TILE, 1), lambda i: (i, 0))
-    par_spec = pl.BlockSpec((3,), lambda i: (0,))
     out_shapes = (
         jax.ShapeDtypeStruct((N, BUF), jnp.int32),  # buf_ev
         jax.ShapeDtypeStruct((N, BUF), jnp.int32),  # buf_valid
@@ -138,7 +131,7 @@ def reps_tick_pallas(
     outs = pl.pallas_call(
         _reps_tick_kernel,
         grid=grid,
-        in_specs=[buf_spec, buf_spec] + [col_spec] * 12 + [par_spec],
+        in_specs=[buf_spec, buf_spec] + [col_spec] * 12 + [SCALAR_ROW_SPEC],
         out_specs=(buf_spec, buf_spec) + (col_spec,) * 7,
         out_shape=out_shapes,
         interpret=interpret,
@@ -149,7 +142,7 @@ def reps_tick_pallas(
         col(exit_freeze), col(n_cached),
         col(ack_mask), col(ack_ev), col(ack_ecn), col(timeout_mask),
         col(send_mask), col(rand_ev),
-        params,
+        scalar_row(now, num_pkts_bdp, freezing_timeout),
     )
     (
         o_buf_ev, o_buf_valid, o_head, o_num_valid, o_explore, o_freezing,

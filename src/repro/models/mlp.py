@@ -20,7 +20,6 @@ from jax.sharding import PartitionSpec as P
 from repro.configs.base import ModelConfig
 from repro.distrib.sharding import active_mesh, resolve_spec, shard
 from repro.models.common import act_fn, dense_init, split_keys
-from repro.utils import compat
 
 
 def init_mlp_params(key, cfg: ModelConfig, dtype=jnp.float32):
@@ -163,7 +162,7 @@ def moe(x, p, cfg: ModelConfig):
         aux = jax.lax.pmean(aux, "model")
         return y, aux
 
-    fn = compat.shard_map(
+    fn = jax.shard_map(
         local_fn,
         mesh=mesh,
         in_specs=(tok_spec, exp_spec),

@@ -102,7 +102,6 @@ from repro.netsim.failures import truncate_dead
 from repro.netsim.metrics import RunSummary, summarize, summarize_sketch
 from repro.netsim.telemetry import TelemetrySpec
 from repro.netsim.tracer import TraceSpec
-from repro.utils import compat
 
 # padded conns start here: far beyond any sweep horizon, still well inside
 # int32 so `now >= start` arithmetic cannot wrap.
@@ -1268,9 +1267,9 @@ class SweepEngine:
                 # device-invariant along CONN_AXIS).
                 carry_spec = self._conn_state_specs()
                 scn_spec = self._conn_scn_specs()
-            body = compat.shard_map(
+            body = jax.shard_map(
                 body,
-                self.mesh,
+                mesh=self.mesh,
                 in_specs=(
                     carry_spec, P(SWEEP_AXIS), scn_spec,
                     P(SWEEP_AXIS), P(),

@@ -15,7 +15,6 @@ import jax
 import jax.numpy as jnp
 
 from repro.models.common import cast_tree
-from repro.utils.compat import grad_safe_barrier
 from repro.models.model_zoo import Model
 from repro.train.optimizer import AdamWConfig, apply_updates, init_opt_state
 
@@ -36,7 +35,7 @@ def make_train_step(model: Model, tcfg: TrainConfig):
         # the barrier XLA sinks the convert into the layer scan, and every
         # layer iteration re-reads the full fp32 parameter stack (measured
         # 59.5 GB/iteration on qwen3-moe — EXPERIMENTS.md §Perf iter 2).
-        p = grad_safe_barrier(p)
+        p = jax.lax.optimization_barrier(p)
         b = dict(batch)
         if "embeds" in b:
             b["embeds"] = b["embeds"].astype(tcfg.compute_dtype)

@@ -1,6 +1,3 @@
-from repro.utils.compat import grad_safe_barrier, shard_map
 from repro.utils.struct import pytree_dataclass, static_field
 
-__all__ = [
-    "grad_safe_barrier", "shard_map", "pytree_dataclass", "static_field",
-]
+__all__ = ["pytree_dataclass", "static_field"]

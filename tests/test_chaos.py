@@ -79,10 +79,12 @@ def test_known_bad_fixture_violates_and_reps_does_not():
 def test_shrink_produces_smaller_bit_exact_replayable_repro(tmp_path):
     c = ChaosCampaign(seed=1)
     # start from an already-small violating scenario so the greedy loop
-    # converges in a handful of runs
+    # converges in a handful of runs: under jax's default random stream,
+    # ECMP hashes a conn of ToR 0's first 8 onto spine 1, where it
+    # livelocks
     seedling = dataclasses.replace(
         known_bad_scenario(ticks=320, chunk=160),
-        faults=(ChaosFault("spine_down", tor=0, spine=3, start=8,
+        faults=(ChaosFault("spine_down", tor=0, spine=1, start=8,
                            end=chaos.failures.FOREVER),),
         msg_pkts=6, n_conns=8,
     )

@@ -160,10 +160,13 @@ def test_repslb_backends_bit_identical():
 # ---------------------------------------------------------------------------
 
 
-@pytest.mark.parametrize("K,S", [(7, 4), (64, 33), (130, 12), (320, 195)])
+@pytest.mark.parametrize(
+    "K,S", [(7, 4), (64, 33), (130, 12), (320, 195), (300, 4500)]
+)
 def test_seg_primitives_match_refs(K, S):
     """seg_rank / seg_sum kernels == the pure-jnp oracles, per element and
-    under vmap (the sweep row axis adds a grid dimension)."""
+    under vmap (the sweep row axis adds a grid dimension); S = 4500 spans
+    three segment tiles, the last one partial."""
     key = jax.random.PRNGKey(K * 1000 + S)
     seg = jax.random.randint(key, (3, K), 0, S + 2, jnp.int32)  # incl. >= S
     vals = jax.random.randint(jax.random.fold_in(key, 1), (3, 5, K), -4, 9,

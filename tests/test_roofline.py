@@ -81,9 +81,7 @@ def test_collective_parse():
 
     from jax.sharding import NamedSharding, PartitionSpec as P
 
-    from repro.utils import compat
-
-    sf = compat.shard_map(f, mesh=mesh, in_specs=P(), out_specs=P(), check_vma=False)
+    sf = jax.shard_map(f, mesh=mesh, in_specs=P(), out_specs=P(), check_vma=False)
     c = jax.jit(sf).lower(jnp.ones((128, 128), jnp.float32)).compile()
     cost = analyze_hlo(c.as_text())
     if len(jax.devices()) > 1:
