@@ -780,7 +780,10 @@ class SwitchLB(LoadBalancer):
 
         def mk(i):
             def br(sts):
-                aux, si = fn(i, sts[i])
+                # one profiler scope per variant, so a trace splits the
+                # switch's device time by load balancer
+                with jax.named_scope(f"lb.{self.variants[i].name}"):
+                    aux, si = fn(i, sts[i])
                 return aux, tuple(
                     si if j == i else sts[j] for j in range(len(sts))
                 )

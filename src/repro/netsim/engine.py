@@ -964,29 +964,30 @@ class Simulator:
         ) = state[1:]
 
         if conn_axis is not None:
-            # conn-sharded entry: gather the small per-conn leaves to full
-            # shape (collective cost O(NC) scalars/tick; the (NC, MSG)
-            # bitmaps stay local).  NCd/coff identify this device's block.
-            NCd = c_inflight.shape[0]
-            coff = jax.lax.axis_index(conn_axis) * NCd
+            with jax.named_scope("tick.conn_exchange"):
+                # conn-sharded entry: gather the small per-conn leaves to full
+                # shape (collective cost O(NC) scalars/tick; the (NC, MSG)
+                # bitmaps stay local).  NCd/coff identify this device's block.
+                NCd = c_inflight.shape[0]
+                coff = jax.lax.axis_index(conn_axis) * NCd
 
-            def cgather(x):
-                return jax.lax.all_gather(x, conn_axis, axis=0, tiled=True)
+                def cgather(x):
+                    return jax.lax.all_gather(x, conn_axis, axis=0, tiled=True)
 
-            (c_inflight, c_next_new, c_delivered, c_rx_pending, c_done,
-             c_done_tick, c_rtx_count, c_cwnd, c_alpha) = (
-                cgather(c_inflight), cgather(c_next_new),
-                cgather(c_delivered), cgather(c_rx_pending),
-                cgather(c_done), cgather(c_done_tick),
-                cgather(c_rtx_count), cgather(c_cwnd), cgather(c_alpha),
-            )
-            scn = scn._replace(
-                conn_src=cgather(scn.conn_src),
-                conn_dst=cgather(scn.conn_dst),
-                conn_msg=cgather(scn.conn_msg),
-                conn_start=cgather(scn.conn_start),
-                conn_dep=cgather(scn.conn_dep),
-            )
+                (c_inflight, c_next_new, c_delivered, c_rx_pending, c_done,
+                 c_done_tick, c_rtx_count, c_cwnd, c_alpha) = (
+                    cgather(c_inflight), cgather(c_next_new),
+                    cgather(c_delivered), cgather(c_rx_pending),
+                    cgather(c_done), cgather(c_done_tick),
+                    cgather(c_rtx_count), cgather(c_cwnd), cgather(c_alpha),
+                )
+                scn = scn._replace(
+                    conn_src=cgather(scn.conn_src),
+                    conn_dst=cgather(scn.conn_dst),
+                    conn_msg=cgather(scn.conn_msg),
+                    conn_start=cgather(scn.conn_start),
+                    conn_dep=cgather(scn.conn_dep),
+                )
 
         sparse = bool(cfg.conn_sharding)
         if sparse:
@@ -997,10 +998,11 @@ class Simulator:
             # through as_idx; because as_idx is kept ascending, the
             # compacted slot sequences are identical to the dense path's,
             # and with A == NP the whole mode is bit-identical to dense.
-            asx = jnp.minimum(as_idx, NP - 1)
-            as_valid = as_idx < NP
-            asg = jnp.where(as_valid, as_idx, NP)  # scatter-drop form
-            entry_ps_a = jnp.where(as_valid, pkt[PS, asx], FREE)
+            with jax.named_scope("tick.active_set"):
+                asx = jnp.minimum(as_idx, NP - 1)
+                as_valid = as_idx < NP
+                asg = jnp.where(as_valid, as_idx, NP)  # scatter-drop form
+                entry_ps_a = jnp.where(as_valid, pkt[PS, asx], FREE)
         else:
             state_at_entry = pkt[PS]
 
@@ -1010,548 +1012,559 @@ class Simulator:
             lb_counts = jnp.zeros((N_TRACE_KINDS,), jnp.int32)
 
         # =============== 1. feedback (ACK / NACK) =====================
-        if sparse:
-            ps_a = entry_ps_a
-            evt_a = pkt[PEVT, asx]
-            due_a = as_valid & ((ps_a == IN_ACK) | (ps_a == IN_NACK)) & (evt_a == now)
-            e_pos = self._compact(due_a, self.MAX_EV)
-            e_idx = jnp.where(
-                e_pos < self.A, as_idx[jnp.minimum(e_pos, self.A - 1)], NP
-            )
-        else:
-            p_state = pkt[PS]
-            due = ((p_state == IN_ACK) | (p_state == IN_NACK)) & (pkt[PEVT] == now)
-            e_idx = self._compact(due, self.MAX_EV)
-        e_valid = e_idx < NP
-        E = pkt[:, jnp.minimum(e_idx, NP - 1)]  # (PF, MAX_EV) one gather
-        e_conn = jnp.where(e_valid, E[PCONN], NC)  # NC = sentinel segment
-        e_is_nack = e_valid & (E[PS] == IN_NACK)
-        e_is_ack = e_valid & ~e_is_nack
-        e_ev = jnp.where(e_valid, E[PEV], 0)
-        e_ecn = e_valid & (E[PECN] == 1)
-        e_cnt = jnp.where(e_valid, E[PACK], 0)
-        e_seq = jnp.where(e_valid, E[PSEQ], 0)
-        e_rtt = jnp.where(e_valid, now - E[PSEND], 0)
-
-        # ONE stacked segment-sum covers the whole feedback stage.  Index =
-        # (ACK round, conn): an ACK's round is its FIFO rank among
-        # same-connection ACKs (slot order, unique per conn — computed once
-        # by the segment-rank primitive, no per-round scatter-min
-        # selection); non-ACK/pad events land via their real conn (rank
-        # within the NC sentinel segment picks an arbitrary row, summed
-        # out) so the round-summed leading fields still aggregate ALL
-        # events, while the ACK-masked trailing fields keep the per-round
-        # table clean.  Without trimming no packet can ever be IN_NACK
-        # (only the arrivals trim branch creates them), so the NACK
-        # bookkeeping — rtx marking, the cwnd decrement, two table fields
-        # and a bitmap scatter — is statically compiled out.
-        R_fb = cfg.feedback_rounds
-        ack_seg = jnp.where(e_is_ack, e_conn, NC)
-        e_rank = self._seg_rank_b(ack_seg, NC + 1)
-        ridx = jnp.minimum(e_rank, R_fb) * (NC + 1) + e_conn
-        fields = [
-            jnp.where(e_is_nack, 1, e_cnt) if cfg.trimming else e_cnt,  # dec
-            e_is_ack.astype(jnp.int32),
-            jnp.where(e_is_ack, e_ev, 0),
-            (e_ecn & e_is_ack).astype(jnp.int32),
-            jnp.where(e_is_ack, e_rtt, 0),
-        ]
-        if cfg.trimming:
-            already = self._bm_get(c_rcv, e_conn, e_seq, conn_axis)
-            need_rtx = e_is_nack & ~already
-            prev_rtx = self._bm_get(c_rtx, e_conn, e_seq, conn_axis)
-            c_rtx = self._bm_max(c_rtx, e_conn, e_seq, need_rtx, conn_axis)
-            fields += [
-                (need_rtx & ~prev_rtx).astype(jnp.int32),
-                e_is_nack.astype(jnp.int32),
-            ]
-        tbl = self._seg_sum_b(
-            ridx, jnp.stack(fields), (R_fb + 1) * (NC + 1)
-        ).reshape(len(fields), R_fb + 1, NC + 1)
-        fb = jnp.sum(tbl, axis=1)  # rank-independent totals per conn
-        c_inflight = c_inflight - fb[0, :NC]
-        if cfg.trimming:
-            c_rtx_count = c_rtx_count + fb[5, :NC]
-            nacks_per_conn = fb[6, :NC]
-            c_cwnd = jnp.clip(
-                c_cwnd - nacks_per_conn.astype(jnp.float32),
-                1.0,
-                float(cfg.max_cwnd_pkts),
-            )
-
-        # LB + CC updates: up to `feedback_rounds` exact rounds of one ACK
-        # event per connection — round r's per-conn event is table row r.
-        # Each round gets its own key off the tick stream (fold 4) so
-        # repath draws differ per seed / row / tick / round; key-ignoring
-        # LBs are bit-identical (fold_in consumes no randomness).
-        k_ack = jax.random.fold_in(key, 4)
-        for r in range(R_fb):
-            conn_mask = tbl[1, r, :NC] > 0
-            conn_ev = tbl[2, r, :NC]
-            conn_ecn = tbl[3, r, :NC] > 0
-            conn_rtt = tbl[4, r, :NC]
-            c_cwnd, c_alpha = self._cc_on_ack(c_cwnd, c_alpha, conn_mask, conn_ecn, conn_rtt)
-            prev_lb = lb_state
-            lb_state = self.lb.on_ack(
-                lb_state, conn_mask, conn_ev, conn_ecn, now,
-                jax.random.fold_in(k_ack, r),
-            )
-            if emit_events:
-                lb_counts = lb_counts + self.lb.trace(
-                    "ack", prev_lb, lb_state, conn_mask
+        with jax.named_scope("tick.feedback"):
+            if sparse:
+                ps_a = entry_ps_a
+                evt_a = pkt[PEVT, asx]
+                due_a = as_valid & ((ps_a == IN_ACK) | (ps_a == IN_NACK)) & (evt_a == now)
+                e_pos = self._compact(due_a, self.MAX_EV)
+                e_idx = jnp.where(
+                    e_pos < self.A, as_idx[jnp.minimum(e_pos, self.A - 1)], NP
                 )
-        unprocessed = jnp.sum(
-            (e_is_ack & (e_rank >= R_fb)).astype(jnp.int32)
-        )
+            else:
+                p_state = pkt[PS]
+                due = ((p_state == IN_ACK) | (p_state == IN_NACK)) & (pkt[PEVT] == now)
+                e_idx = self._compact(due, self.MAX_EV)
+            e_valid = e_idx < NP
+            E = pkt[:, jnp.minimum(e_idx, NP - 1)]  # (PF, MAX_EV) one gather
+            e_conn = jnp.where(e_valid, E[PCONN], NC)  # NC = sentinel segment
+            e_is_nack = e_valid & (E[PS] == IN_NACK)
+            e_is_ack = e_valid & ~e_is_nack
+            e_ev = jnp.where(e_valid, E[PEV], 0)
+            e_ecn = e_valid & (E[PECN] == 1)
+            e_cnt = jnp.where(e_valid, E[PACK], 0)
+            e_seq = jnp.where(e_valid, E[PSEQ], 0)
+            e_rtt = jnp.where(e_valid, now - E[PSEND], 0)
+
+            # ONE stacked segment-sum covers the whole feedback stage.  Index =
+            # (ACK round, conn): an ACK's round is its FIFO rank among
+            # same-connection ACKs (slot order, unique per conn — computed once
+            # by the segment-rank primitive, no per-round scatter-min
+            # selection); non-ACK/pad events land via their real conn (rank
+            # within the NC sentinel segment picks an arbitrary row, summed
+            # out) so the round-summed leading fields still aggregate ALL
+            # events, while the ACK-masked trailing fields keep the per-round
+            # table clean.  Without trimming no packet can ever be IN_NACK
+            # (only the arrivals trim branch creates them), so the NACK
+            # bookkeeping — rtx marking, the cwnd decrement, two table fields
+            # and a bitmap scatter — is statically compiled out.
+            R_fb = cfg.feedback_rounds
+            ack_seg = jnp.where(e_is_ack, e_conn, NC)
+            e_rank = self._seg_rank_b(ack_seg, NC + 1)
+            ridx = jnp.minimum(e_rank, R_fb) * (NC + 1) + e_conn
+            fields = [
+                jnp.where(e_is_nack, 1, e_cnt) if cfg.trimming else e_cnt,  # dec
+                e_is_ack.astype(jnp.int32),
+                jnp.where(e_is_ack, e_ev, 0),
+                (e_ecn & e_is_ack).astype(jnp.int32),
+                jnp.where(e_is_ack, e_rtt, 0),
+            ]
+            if cfg.trimming:
+                already = self._bm_get(c_rcv, e_conn, e_seq, conn_axis)
+                need_rtx = e_is_nack & ~already
+                prev_rtx = self._bm_get(c_rtx, e_conn, e_seq, conn_axis)
+                c_rtx = self._bm_max(c_rtx, e_conn, e_seq, need_rtx, conn_axis)
+                fields += [
+                    (need_rtx & ~prev_rtx).astype(jnp.int32),
+                    e_is_nack.astype(jnp.int32),
+                ]
+            tbl = self._seg_sum_b(
+                ridx, jnp.stack(fields), (R_fb + 1) * (NC + 1)
+            ).reshape(len(fields), R_fb + 1, NC + 1)
+            fb = jnp.sum(tbl, axis=1)  # rank-independent totals per conn
+            c_inflight = c_inflight - fb[0, :NC]
+            if cfg.trimming:
+                c_rtx_count = c_rtx_count + fb[5, :NC]
+                nacks_per_conn = fb[6, :NC]
+                c_cwnd = jnp.clip(
+                    c_cwnd - nacks_per_conn.astype(jnp.float32),
+                    1.0,
+                    float(cfg.max_cwnd_pkts),
+                )
+
+            # LB + CC updates: up to `feedback_rounds` exact rounds of one ACK
+            # event per connection — round r's per-conn event is table row r.
+            # Each round gets its own key off the tick stream (fold 4) so
+            # repath draws differ per seed / row / tick / round; key-ignoring
+            # LBs are bit-identical (fold_in consumes no randomness).
+            k_ack = jax.random.fold_in(key, 4)
+            for r in range(R_fb):
+                conn_mask = tbl[1, r, :NC] > 0
+                conn_ev = tbl[2, r, :NC]
+                conn_ecn = tbl[3, r, :NC] > 0
+                conn_rtt = tbl[4, r, :NC]
+                c_cwnd, c_alpha = self._cc_on_ack(c_cwnd, c_alpha, conn_mask, conn_ecn, conn_rtt)
+                with jax.named_scope("tick.lb"):
+                    prev_lb = lb_state
+                    lb_state = self.lb.on_ack(
+                        lb_state, conn_mask, conn_ev, conn_ecn, now,
+                        jax.random.fold_in(k_ack, r),
+                    )
+                    if emit_events:
+                        lb_counts = lb_counts + self.lb.trace(
+                            "ack", prev_lb, lb_state, conn_mask
+                        )
+            unprocessed = jnp.sum(
+                (e_is_ack & (e_rank >= R_fb)).astype(jnp.int32)
+            )
 
         # =============== 2. RTO ========================================
-        # A packet fires its RTO exactly at send_tick + rto_ticks (send_tick
-        # is set once at injection and eligibility blockers — orphan, conn
-        # done — are permanent), and injection admits ≤ 1 packet per host
-        # per tick, so ≤ NH packets fire per tick: compact to NH rows and
-        # keep every scatter narrow instead of full packet-table width.
-        if sparse:
-            ps_a = jnp.where(due_a, FREE, ps_a)  # free feedback slots
-            porph_a = pkt[PORPH, asx] == 1
-            active_a = (ps_a == FLYING) | (ps_a == QUEUED) | (ps_a == LOST_WAIT)
-            cdone_a = c_done[jnp.clip(pkt[PCONN, asx], 0, NC - 1)]
-            rto_a = (
-                active_a
-                & ~porph_a
-                & ((now - pkt[PSEND, asx]) >= cfg.rto_ticks)
-                & ~cdone_a
-                & as_valid
+        with jax.named_scope("tick.rto"):
+            # A packet fires its RTO exactly at send_tick + rto_ticks (send_tick
+            # is set once at injection and eligibility blockers — orphan, conn
+            # done — are permanent), and injection admits ≤ 1 packet per host
+            # per tick, so ≤ NH packets fire per tick: compact to NH rows and
+            # keep every scatter narrow instead of full packet-table width.
+            if sparse:
+                ps_a = jnp.where(due_a, FREE, ps_a)  # free feedback slots
+                porph_a = pkt[PORPH, asx] == 1
+                active_a = (ps_a == FLYING) | (ps_a == QUEUED) | (ps_a == LOST_WAIT)
+                cdone_a = c_done[jnp.clip(pkt[PCONN, asx], 0, NC - 1)]
+                rto_a = (
+                    active_a
+                    & ~porph_a
+                    & ((now - pkt[PSEND, asx]) >= cfg.rto_ticks)
+                    & ~cdone_a
+                    & as_valid
+                )
+                r_pos = self._compact(rto_a, NH)
+                r_idx = jnp.where(
+                    r_pos < self.A, as_idx[jnp.minimum(r_pos, self.A - 1)], NP
+                )
+                timeouts_d = jnp.sum(rto_a.astype(jnp.int32))
+            else:
+                # free all feedback slots
+                p_state = jnp.where(due, FREE, p_state)
+                p_conn = pkt[PCONN]
+                p_orphan = pkt[PORPH] == 1
+                active_data = (p_state == FLYING) | (p_state == QUEUED) | (p_state == LOST_WAIT)
+                conn_done_of_pkt = c_done[jnp.clip(p_conn, 0, NC - 1)]
+                rto = (
+                    active_data
+                    & ~p_orphan
+                    & ((now - pkt[PSEND]) >= cfg.rto_ticks)
+                    & ~conn_done_of_pkt
+                )
+                r_idx = self._compact(rto, NH)
+                timeouts_d = jnp.sum(rto.astype(jnp.int32))
+            r_valid = r_idx < NP
+            Rp = pkt[:, jnp.minimum(r_idx, NP - 1)]  # (PF, NH)
+            r_conn = jnp.where(r_valid, Rp[PCONN], NC)
+            r_seq = jnp.where(r_valid, Rp[PSEQ], 0)
+            rcv_already = self._bm_get(c_rcv, r_conn, r_seq, conn_axis)
+            rto_need = r_valid & ~rcv_already
+            prev_rtx_p = self._bm_get(c_rtx, r_conn, r_seq, conn_axis)
+            c_rtx = self._bm_max(
+                c_rtx, jnp.where(rto_need, r_conn, NC), r_seq, rto_need, conn_axis
             )
-            r_pos = self._compact(rto_a, NH)
-            r_idx = jnp.where(
-                r_pos < self.A, as_idx[jnp.minimum(r_pos, self.A - 1)], NP
+            rsum_rto = self._seg_sum_b(
+                r_conn,
+                jnp.stack([
+                    (rto_need & ~prev_rtx_p).astype(jnp.int32),
+                    r_valid.astype(jnp.int32),
+                ]),
+                NC + 1,
             )
-            timeouts_d = jnp.sum(rto_a.astype(jnp.int32))
-        else:
-            # free all feedback slots
-            p_state = jnp.where(due, FREE, p_state)
-            p_conn = pkt[PCONN]
-            p_orphan = pkt[PORPH] == 1
-            active_data = (p_state == FLYING) | (p_state == QUEUED) | (p_state == LOST_WAIT)
-            conn_done_of_pkt = c_done[jnp.clip(p_conn, 0, NC - 1)]
-            rto = (
-                active_data
-                & ~p_orphan
-                & ((now - pkt[PSEND]) >= cfg.rto_ticks)
-                & ~conn_done_of_pkt
+            c_rtx_count = c_rtx_count + rsum_rto[0, :NC]
+            rto_per_conn = rsum_rto[1, :NC]
+            c_inflight = c_inflight - rto_per_conn
+            c_cwnd = jnp.clip(
+                c_cwnd - rto_per_conn.astype(jnp.float32), 1.0, float(cfg.max_cwnd_pkts)
             )
-            r_idx = self._compact(rto, NH)
-            timeouts_d = jnp.sum(rto.astype(jnp.int32))
-        r_valid = r_idx < NP
-        Rp = pkt[:, jnp.minimum(r_idx, NP - 1)]  # (PF, NH)
-        r_conn = jnp.where(r_valid, Rp[PCONN], NC)
-        r_seq = jnp.where(r_valid, Rp[PSEQ], 0)
-        rcv_already = self._bm_get(c_rcv, r_conn, r_seq, conn_axis)
-        rto_need = r_valid & ~rcv_already
-        prev_rtx_p = self._bm_get(c_rtx, r_conn, r_seq, conn_axis)
-        c_rtx = self._bm_max(
-            c_rtx, jnp.where(rto_need, r_conn, NC), r_seq, rto_need, conn_axis
-        )
-        rsum_rto = self._seg_sum_b(
-            r_conn,
-            jnp.stack([
-                (rto_need & ~prev_rtx_p).astype(jnp.int32),
-                r_valid.astype(jnp.int32),
-            ]),
-            NC + 1,
-        )
-        c_rtx_count = c_rtx_count + rsum_rto[0, :NC]
-        rto_per_conn = rsum_rto[1, :NC]
-        c_inflight = c_inflight - rto_per_conn
-        c_cwnd = jnp.clip(
-            c_cwnd - rto_per_conn.astype(jnp.float32), 1.0, float(cfg.max_cwnd_pkts)
-        )
-        prev_lb = lb_state
-        lb_state = self.lb.on_timeout(
-            lb_state, rto_per_conn > 0, now, jax.random.fold_in(key, 5)
-        )
-        if emit_events:
-            lb_counts = lb_counts + self.lb.trace(
-                "timeout", prev_lb, lb_state, rto_per_conn > 0
-            )
-        # orphan in-network packets; free LOST_WAIT ones — write the two
-        # packet columns (state / orphan) back once (active rows only in
-        # sparse mode; untracked slots are FREE and untouched either way)
-        if sparse:
-            porph_a = porph_a | rto_a
-            ps_a = jnp.where(rto_a & (ps_a == LOST_WAIT), FREE, ps_a)
-            pkt = pkt.at[PS, asg].set(ps_a, mode="drop")
-            pkt = pkt.at[PORPH, asg].set(porph_a.astype(jnp.int32), mode="drop")
-        else:
-            p_orphan = p_orphan | rto
-            p_state = jnp.where(rto & (p_state == LOST_WAIT), FREE, p_state)
-            pkt = pkt.at[PS].set(p_state)
-            pkt = pkt.at[PORPH].set(p_orphan.astype(jnp.int32))
+            with jax.named_scope("tick.lb"):
+                prev_lb = lb_state
+                lb_state = self.lb.on_timeout(
+                    lb_state, rto_per_conn > 0, now, jax.random.fold_in(key, 5)
+                )
+                if emit_events:
+                    lb_counts = lb_counts + self.lb.trace(
+                        "timeout", prev_lb, lb_state, rto_per_conn > 0
+                    )
+            # orphan in-network packets; free LOST_WAIT ones — write the two
+            # packet columns (state / orphan) back once (active rows only in
+            # sparse mode; untracked slots are FREE and untouched either way)
+            if sparse:
+                porph_a = porph_a | rto_a
+                ps_a = jnp.where(rto_a & (ps_a == LOST_WAIT), FREE, ps_a)
+                pkt = pkt.at[PS, asg].set(ps_a, mode="drop")
+                pkt = pkt.at[PORPH, asg].set(porph_a.astype(jnp.int32), mode="drop")
+            else:
+                p_orphan = p_orphan | rto
+                p_state = jnp.where(rto & (p_state == LOST_WAIT), FREE, p_state)
+                pkt = pkt.at[PS].set(p_state)
+                pkt = pkt.at[PORPH].set(p_orphan.astype(jnp.int32))
 
         # =============== 3. service / dequeue ===========================
-        f_active = (now >= scn.f_start) & (now < scn.f_end)
-        failed_q = (
-            jnp.zeros((NQ + 1,), jnp.bool_)
-            .at[jnp.where(f_active & (scn.f_kind == K_DOWN), scn.f_queue, NQ)]
-            .max(True, mode="drop")[:NQ]
-        )
-        degraded_q = (
-            jnp.zeros((NQ + 1,), jnp.bool_)
-            .at[jnp.where(f_active & (scn.f_kind == K_DEGRADED), scn.f_queue, NQ)]
-            .max(True, mode="drop")[:NQ]
-        )
-        # gray loss: per-queue fixed-point drop probability (param/GRAY_SCALE)
-        # scatter-maxed from active kind-2 rows, compared against a uniform
-        # draw on its own fold (3) of the tick key — independent of the RED
-        # (1) and LB (2) streams, so schedules with no gray rows stay
-        # bit-identical to runs predating the gray fault model.
-        gray_p = (
-            jnp.zeros((NQ + 1,), jnp.int32)
-            .at[jnp.where(f_active & (scn.f_kind == K_GRAY), scn.f_queue, NQ)]
-            .max(scn.f_param, mode="drop")[:NQ]
-        )
-        u_gray = jax.random.uniform(jax.random.fold_in(key, 3), (NQ,))
-        gray_hit = (u_gray * GRAY_SCALE).astype(jnp.int32) < gray_p
-        service_ok = ~(degraded_q & (now % 2 == 1))
-        serve = (q_len > 0) & service_ok
-        head_pid = qbuf[jnp.arange(NQ), q_head % QCAP]
-        q_head = jnp.where(serve, q_head + 1, q_head)
-        q_len = jnp.where(serve, q_len - 1, q_len)
-        q_served = q_served + serve.astype(jnp.int32)
+        with jax.named_scope("tick.service"):
+            f_active = (now >= scn.f_start) & (now < scn.f_end)
+            failed_q = (
+                jnp.zeros((NQ + 1,), jnp.bool_)
+                .at[jnp.where(f_active & (scn.f_kind == K_DOWN), scn.f_queue, NQ)]
+                .max(True, mode="drop")[:NQ]
+            )
+            degraded_q = (
+                jnp.zeros((NQ + 1,), jnp.bool_)
+                .at[jnp.where(f_active & (scn.f_kind == K_DEGRADED), scn.f_queue, NQ)]
+                .max(True, mode="drop")[:NQ]
+            )
+            # gray loss: per-queue fixed-point drop probability (param/GRAY_SCALE)
+            # scatter-maxed from active kind-2 rows, compared against a uniform
+            # draw on its own fold (3) of the tick key — independent of the RED
+            # (1) and LB (2) streams, so schedules with no gray rows stay
+            # bit-identical to runs predating the gray fault model.
+            gray_p = (
+                jnp.zeros((NQ + 1,), jnp.int32)
+                .at[jnp.where(f_active & (scn.f_kind == K_GRAY), scn.f_queue, NQ)]
+                .max(scn.f_param, mode="drop")[:NQ]
+            )
+            u_gray = jax.random.uniform(jax.random.fold_in(key, 3), (NQ,))
+            gray_hit = (u_gray * GRAY_SCALE).astype(jnp.int32) < gray_p
+            service_ok = ~(degraded_q & (now % 2 == 1))
+            serve = (q_len > 0) & service_ok
+            head_pid = qbuf[jnp.arange(NQ), q_head % QCAP]
+            q_head = jnp.where(serve, q_head + 1, q_head)
+            q_len = jnp.where(serve, q_len - 1, q_len)
+            q_served = q_served + serve.astype(jnp.int32)
 
-        pid = jnp.where(serve, head_pid, NP)  # NP = drop sentinel
-        qid = jnp.arange(NQ, dtype=jnp.int32)
-        # gray-dropped serves share the blackhole path (silent loss →
-        # ST_DROPS_FAIL, LOST_WAIT awaiting RTO) but NOT the q_len_eff
-        # routing penalty below: gray loss is invisible to the switches.
-        blackhole = serve & (failed_q | gray_hit)
-        is_final = serve & ~blackhole & (qid >= topo.t0_down_base)
-        mid = serve & ~blackhole & ~is_final
+            pid = jnp.where(serve, head_pid, NP)  # NP = drop sentinel
+            qid = jnp.arange(NQ, dtype=jnp.int32)
+            # gray-dropped serves share the blackhole path (silent loss →
+            # ST_DROPS_FAIL, LOST_WAIT awaiting RTO) but NOT the q_len_eff
+            # routing penalty below: gray loss is invisible to the switches.
+            blackhole = serve & (failed_q | gray_hit)
+            is_final = serve & ~blackhole & (qid >= topo.t0_down_base)
+            mid = serve & ~blackhole & ~is_final
 
-        D = pkt[:, jnp.minimum(pid, NP - 1)]  # (PF, NQ) served-packet rows
-        d_orph = serve & (D[PORPH] == 1)
+            D = pkt[:, jnp.minimum(pid, NP - 1)]  # (PF, NQ) served-packet rows
+            d_orph = serve & (D[PORPH] == 1)
 
-        # blackholed: silent loss (failure — no trim); orphans are freed
-        drops_fail_d = jnp.sum((blackhole & ~d_orph).astype(jnp.int32))
+            # blackholed: silent loss (failure — no trim); orphans are freed
+            drops_fail_d = jnp.sum((blackhole & ~d_orph).astype(jnp.int32))
 
-        # deliveries (≤ 1 per connection per tick — host downlink serves 1)
-        dconn = jnp.where(is_final, D[PCONN], NC)
-        dseq = jnp.where(is_final, D[PSEQ], 0)
-        # deliveries only happen at the final-hop queues — the STATIC tail
-        # [t0_down_base, NQ) of the queue axis (NH host downlinks) — so the
-        # delivery-side scatters restrict to that slice: the dropped rows
-        # are all sentinel/False no-ops, and scatter cost is rows × K
-        fin = slice(topo.t0_down_base, NQ)
-        was_done = c_done.at[dconn].get(mode="fill", fill_value=True)
-        newly = is_final & ~self._bm_get(c_rcv, dconn, dseq, conn_axis)
-        c_rcv = self._bm_max(
-            c_rcv, dconn[fin], dseq[fin], is_final[fin], conn_axis
-        )
-        delivered_d = jnp.sum(newly.astype(jnp.int32))
-        deliver_ackable = is_final & ~d_orph & ~was_done
-        msg_of = scn.conn_msg.at[dconn].get(mode="fill", fill_value=BIG)
-        # ≤1 delivery per conn per tick ⇒ the post-update per-conn counters
-        # equal the pre-update gathers plus this queue's own contribution —
-        # so `emit`/`first_done` are computable BEFORE the scatter and the
-        # whole stage needs ONE stacked segment-sum.
-        del_of = (
-            c_delivered.at[dconn].get(mode="fill", fill_value=0)
-            + newly.astype(jnp.int32)
-        )
-        now_done = del_of >= msg_of
-        rxp = (
-            c_rx_pending.at[dconn].get(mode="fill", fill_value=0)
-            + deliver_ackable.astype(jnp.int32)
-        )
-        emit = deliver_ackable & ((rxp >= cfg.ack_coalesce) | now_done)
-        first_done = is_final & now_done & ~was_done
-        dsum = self._seg_sum_b(
-            dconn[fin],
-            jnp.stack([
-                newly.astype(jnp.int32)[fin],
-                deliver_ackable.astype(jnp.int32)[fin],
-                emit.astype(jnp.int32)[fin],
-                first_done.astype(jnp.int32)[fin],
-            ]),
-            NC + 1,
-        )
-        c_delivered = c_delivered + dsum[0, :NC]
-        c_rx_pending = jnp.where(
-            dsum[2, :NC] > 0, 0, c_rx_pending + dsum[1, :NC]
-        )
-        # completion bookkeeping
-        first_done_c = dsum[3, :NC] > 0
-        c_done = c_done | first_done_c
-        c_done_tick = jnp.where(first_done_c, now, c_done_tick)
+            # deliveries (≤ 1 per connection per tick — host downlink serves 1)
+            dconn = jnp.where(is_final, D[PCONN], NC)
+            dseq = jnp.where(is_final, D[PSEQ], 0)
+            # deliveries only happen at the final-hop queues — the STATIC tail
+            # [t0_down_base, NQ) of the queue axis (NH host downlinks) — so the
+            # delivery-side scatters restrict to that slice: the dropped rows
+            # are all sentinel/False no-ops, and scatter cost is rows × K
+            fin = slice(topo.t0_down_base, NQ)
+            was_done = c_done.at[dconn].get(mode="fill", fill_value=True)
+            newly = is_final & ~self._bm_get(c_rcv, dconn, dseq, conn_axis)
+            c_rcv = self._bm_max(
+                c_rcv, dconn[fin], dseq[fin], is_final[fin], conn_axis
+            )
+            delivered_d = jnp.sum(newly.astype(jnp.int32))
+            deliver_ackable = is_final & ~d_orph & ~was_done
+            msg_of = scn.conn_msg.at[dconn].get(mode="fill", fill_value=BIG)
+            # ≤1 delivery per conn per tick ⇒ the post-update per-conn counters
+            # equal the pre-update gathers plus this queue's own contribution —
+            # so `emit`/`first_done` are computable BEFORE the scatter and the
+            # whole stage needs ONE stacked segment-sum.
+            del_of = (
+                c_delivered.at[dconn].get(mode="fill", fill_value=0)
+                + newly.astype(jnp.int32)
+            )
+            now_done = del_of >= msg_of
+            rxp = (
+                c_rx_pending.at[dconn].get(mode="fill", fill_value=0)
+                + deliver_ackable.astype(jnp.int32)
+            )
+            emit = deliver_ackable & ((rxp >= cfg.ack_coalesce) | now_done)
+            first_done = is_final & now_done & ~was_done
+            dsum = self._seg_sum_b(
+                dconn[fin],
+                jnp.stack([
+                    newly.astype(jnp.int32)[fin],
+                    deliver_ackable.astype(jnp.int32)[fin],
+                    emit.astype(jnp.int32)[fin],
+                    first_done.astype(jnp.int32)[fin],
+                ]),
+                NC + 1,
+            )
+            c_delivered = c_delivered + dsum[0, :NC]
+            c_rx_pending = jnp.where(
+                dsum[2, :NC] > 0, 0, c_rx_pending + dsum[1, :NC]
+            )
+            # completion bookkeeping
+            first_done_c = dsum[3, :NC] > 0
+            c_done = c_done | first_done_c
+            c_done_tick = jnp.where(first_done_c, now, c_done_tick)
 
-        # served-packet row rewrite (one scatter): blackhole / mid / final
-        d_state = jnp.where(
-            blackhole,
-            jnp.where(d_orph, FREE, LOST_WAIT),
-            jnp.where(
+            # served-packet row rewrite (one scatter): blackhole / mid / final
+            d_state = jnp.where(
+                blackhole,
+                jnp.where(d_orph, FREE, LOST_WAIT),
+                jnp.where(
+                    mid,
+                    FLYING,
+                    jnp.where(emit, IN_ACK, FREE),  # final hop: emitted ACK reuses slot
+                ),
+            )
+            d_evt = jnp.where(
                 mid,
-                FLYING,
-                jnp.where(emit, IN_ACK, FREE),  # final hop: emitted ACK reuses slot
-            ),
-        )
-        d_evt = jnp.where(
-            mid,
-            now + cfg.hop_latency_ticks,
-            jnp.where(emit, now + cfg.ack_delay_ticks, D[PEVT]),
-        )
-        Dn = D.at[PS].set(d_state)
-        Dn = Dn.at[PEVT].set(d_evt)
-        Dn = Dn.at[PHOP].set(jnp.where(mid, D[PHOP] + 1, D[PHOP]))
-        Dn = Dn.at[PCURQ].set(jnp.where(mid, qid, D[PCURQ]))
-        Dn = Dn.at[PACK].set(jnp.where(emit, rxp, D[PACK]))
-        pkt = pkt.at[:, pid].set(Dn, mode="drop")
+                now + cfg.hop_latency_ticks,
+                jnp.where(emit, now + cfg.ack_delay_ticks, D[PEVT]),
+            )
+            Dn = D.at[PS].set(d_state)
+            Dn = Dn.at[PEVT].set(d_evt)
+            Dn = Dn.at[PHOP].set(jnp.where(mid, D[PHOP] + 1, D[PHOP]))
+            Dn = Dn.at[PCURQ].set(jnp.where(mid, qid, D[PCURQ]))
+            Dn = Dn.at[PACK].set(jnp.where(emit, rxp, D[PACK]))
+            pkt = pkt.at[:, pid].set(Dn, mode="drop")
 
         # =============== 4. arrivals / enqueue ==========================
-        if sparse:
-            arr_a = (
-                as_valid
-                & (pkt[PS, asx] == FLYING)
-                & (pkt[PEVT, asx] == now)
+        with jax.named_scope("tick.arrivals"):
+            if sparse:
+                arr_a = (
+                    as_valid
+                    & (pkt[PS, asx] == FLYING)
+                    & (pkt[PEVT, asx] == now)
+                )
+                a_pos = self._compact(arr_a, self.MAX_ARR)
+                a_idx = jnp.where(
+                    a_pos < self.A, as_idx[jnp.minimum(a_pos, self.A - 1)], NP
+                )
+            else:
+                p_state = pkt[PS]
+                arr = (p_state == FLYING) & (pkt[PEVT] == now)
+                a_idx = self._compact(arr, self.MAX_ARR)
+            a_valid = a_idx < NP
+            A = pkt[:, jnp.minimum(a_idx, NP - 1)]  # (PF, MAX_ARR)
+            a_conn = jnp.where(a_valid, A[PCONN], 0)
+            a_ev = jnp.where(a_valid, A[PEV], 0)
+            a_inj = jnp.where(a_valid, A[PHOP], 1) == 0
+            a_cur = jnp.where(a_valid, A[PCURQ], 0)
+            a_src = scn.conn_src[jnp.clip(a_conn, 0, NC - 1)]
+            a_dst = scn.conn_dst[jnp.clip(a_conn, 0, NC - 1)]
+            # adaptive switches exclude locally-known failed ports (link down is
+            # visible at the switch); hashing LBs ignore q_len entirely.
+            q_len_eff = q_len + failed_q.astype(jnp.int32) * jnp.int32(4 * QCAP)
+            target = topo.next_queue(
+                a_inj, a_cur, a_conn, a_ev, a_src, a_dst, q_len_eff,
+                adaptive=self.lb.switch_adaptive,
             )
-            a_pos = self._compact(arr_a, self.MAX_ARR)
-            a_idx = jnp.where(
-                a_pos < self.A, as_idx[jnp.minimum(a_pos, self.A - 1)], NP
-            )
-        else:
-            p_state = pkt[PS]
-            arr = (p_state == FLYING) & (pkt[PEVT] == now)
-            a_idx = self._compact(arr, self.MAX_ARR)
-        a_valid = a_idx < NP
-        A = pkt[:, jnp.minimum(a_idx, NP - 1)]  # (PF, MAX_ARR)
-        a_conn = jnp.where(a_valid, A[PCONN], 0)
-        a_ev = jnp.where(a_valid, A[PEV], 0)
-        a_inj = jnp.where(a_valid, A[PHOP], 1) == 0
-        a_cur = jnp.where(a_valid, A[PCURQ], 0)
-        a_src = scn.conn_src[jnp.clip(a_conn, 0, NC - 1)]
-        a_dst = scn.conn_dst[jnp.clip(a_conn, 0, NC - 1)]
-        # adaptive switches exclude locally-known failed ports (link down is
-        # visible at the switch); hashing LBs ignore q_len entirely.
-        q_len_eff = q_len + failed_q.astype(jnp.int32) * jnp.int32(4 * QCAP)
-        target = topo.next_queue(
-            a_inj, a_cur, a_conn, a_ev, a_src, a_dst, q_len_eff,
-            adaptive=self.lb.switch_adaptive,
-        )
-        target = jnp.where(a_valid, target, NQ)
-        u_red = jax.random.uniform(jax.random.fold_in(key, 1), (self.MAX_ARR,))
+            target = jnp.where(a_valid, target, NQ)
+            u_red = jax.random.uniform(jax.random.fold_in(key, 1), (self.MAX_ARR,))
 
-        arrivals_backend = cfg.arrivals_backend
-        if arrivals_backend == "auto":
-            arrivals_backend = (
-                "pallas" if jax.default_backend() == "tpu" else "jnp"
-            )
-        if arrivals_backend == "pallas":
-            # fused serve+rank+accept kernel (repro.kernels.queue_tick);
-            # service already happened, so serve mask is all-zero here.
-            from repro.kernels import ops as kernel_ops
+            arrivals_backend = cfg.arrivals_backend
+            if arrivals_backend == "auto":
+                arrivals_backend = (
+                    "pallas" if jax.default_backend() == "tpu" else "jnp"
+                )
+            if arrivals_backend == "pallas":
+                # fused serve+rank+accept kernel (repro.kernels.queue_tick);
+                # service already happened, so serve mask is all-zero here.
+                from repro.kernels import ops as kernel_ops
 
-            new_qlen, k_accept, _, pos = kernel_ops.queue_tick(
-                target, u_red, q_len, jnp.zeros((NQ,), jnp.int32),
-                QCAP, cfg.kmin, cfg.kmax,
+                new_qlen, k_accept, _, pos = kernel_ops.queue_tick(
+                    target, u_red, q_len, jnp.zeros((NQ,), jnp.int32),
+                    QCAP, cfg.kmin, cfg.kmax,
+                )
+                accept = a_valid & k_accept
+                q_len = new_qlen
+            else:
+                # FIFO rank among same-target arrivals (stable in slot order)
+                rank = self._seg_rank_b(target, NQ + 1)
+                qlen_t = q_len.at[target].get(mode="fill", fill_value=0)
+                accept = a_valid & (rank < QCAP - qlen_t)
+                pos = qlen_t + rank
+                q_len = q_len.at[jnp.where(accept, target, NQ)].add(1, mode="drop")
+            dropd = a_valid & ~accept
+            mark_p = (
+                jnp.clip(
+                    (pos.astype(jnp.float32) - cfg.kmin) / float(cfg.kmax - cfg.kmin),
+                    0.0,
+                    1.0,
+                )
+                * cfg.pmax
             )
-            accept = a_valid & k_accept
-            q_len = new_qlen
-        else:
-            # FIFO rank among same-target arrivals (stable in slot order)
-            rank = self._seg_rank_b(target, NQ + 1)
-            qlen_t = q_len.at[target].get(mode="fill", fill_value=0)
-            accept = a_valid & (rank < QCAP - qlen_t)
-            pos = qlen_t + rank
-            q_len = q_len.at[jnp.where(accept, target, NQ)].add(1, mode="drop")
-        dropd = a_valid & ~accept
-        mark_p = (
-            jnp.clip(
-                (pos.astype(jnp.float32) - cfg.kmin) / float(cfg.kmax - cfg.kmin),
-                0.0,
-                1.0,
+            mark = accept & (u_red < mark_p)
+            ecn_marks_d = jnp.sum(mark.astype(jnp.int32))
+            slot = (q_head.at[target].get(mode="fill", fill_value=0) + pos) % QCAP
+            qbuf = qbuf.at[jnp.where(accept, target, NQ), slot].set(
+                a_idx, mode="drop"
             )
-            * cfg.pmax
-        )
-        mark = accept & (u_red < mark_p)
-        ecn_marks_d = jnp.sum(mark.astype(jnp.int32))
-        slot = (q_head.at[target].get(mode="fill", fill_value=0) + pos) % QCAP
-        qbuf = qbuf.at[jnp.where(accept, target, NQ), slot].set(
-            a_idx, mode="drop"
-        )
-        # congestion drops: trim → NACK; else silent (await RTO); orphans free
-        a_orph = a_valid & (A[PORPH] == 1)
-        drops_cong_d = jnp.sum((dropd & ~a_orph).astype(jnp.int32))
-        if cfg.trimming:
-            dstate = jnp.where(a_orph, FREE, IN_NACK)
-        else:
-            dstate = jnp.where(a_orph, FREE, LOST_WAIT)
-        An = A.at[PS].set(jnp.where(accept, QUEUED, dstate))
-        An = An.at[PCURQ].set(jnp.where(accept, target, A[PCURQ]))
-        An = An.at[PECN].set(A[PECN] | mark.astype(jnp.int32))
-        if cfg.trimming:
-            An = An.at[PEVT].set(
-                jnp.where(dropd & ~a_orph, now + cfg.nack_delay_ticks, A[PEVT])
-            )
-        pkt = pkt.at[:, a_idx].set(An, mode="drop")
+            # congestion drops: trim → NACK; else silent (await RTO); orphans free
+            a_orph = a_valid & (A[PORPH] == 1)
+            drops_cong_d = jnp.sum((dropd & ~a_orph).astype(jnp.int32))
+            if cfg.trimming:
+                dstate = jnp.where(a_orph, FREE, IN_NACK)
+            else:
+                dstate = jnp.where(a_orph, FREE, LOST_WAIT)
+            An = A.at[PS].set(jnp.where(accept, QUEUED, dstate))
+            An = An.at[PCURQ].set(jnp.where(accept, target, A[PCURQ]))
+            An = An.at[PECN].set(A[PECN] | mark.astype(jnp.int32))
+            if cfg.trimming:
+                An = An.at[PEVT].set(
+                    jnp.where(dropd & ~a_orph, now + cfg.nack_delay_ticks, A[PEVT])
+                )
+            pkt = pkt.at[:, a_idx].set(An, mode="drop")
 
         # =============== 5. injection ===================================
-        started = (now >= scn.conn_start) & (
-            (scn.conn_dep < 0) | c_done[jnp.clip(scn.conn_dep, 0, NC - 1)]
-        )
-        has_work = (c_rtx_count > 0) | (c_next_new < scn.conn_msg)
-        can = (
-            started
-            & ~c_done
-            & has_work
-            & (c_inflight < jnp.floor(c_cwnd).astype(jnp.int32))
-        )
-        hc = scn.host_conns  # (NH, CPH)
-        elig = can[jnp.clip(hc, 0, NC - 1)] & (hc >= 0)
-        ordr = (jnp.arange(self.CPH)[None, :] - h_rr[:, None]) % self.CPH
-        score = jnp.where(elig, ordr, BIG)
-        pick_local = jnp.argmin(score, axis=1).astype(jnp.int32)
-        any_pick = jnp.min(score, axis=1) < BIG
-        # free-slot allocation (ring pop)
-        srank = jnp.cumsum(any_pick.astype(jnp.int32)) - 1
-        can_alloc = srank < fl_count
-        if sparse:
-            # active-set capacity gate.  Since every non-FREE slot is
-            # tracked, as_count + fl_count == NP always — so with A == NP
-            # this conjunct is exactly `srank < fl_count` again and the
-            # sparse path stays bit-identical to dense; when A binds, the
-            # overflow surfaces as counted alloc-fails, never lost slots.
-            can_alloc = can_alloc & (as_count + srank < self.A)
-        sendh = any_pick & can_alloc
-        alloc_fail_d = jnp.sum((any_pick & ~can_alloc).astype(jnp.int32))
-        n_alloc = jnp.sum(sendh.astype(jnp.int32))
-        slot_p = fl[(fl_head + srank) % NP]
-        fl_head = (fl_head + n_alloc) % NP
-        fl_count = fl_count - n_alloc
-
-        pick_conn = jnp.where(
-            sendh, hc[jnp.arange(NH), pick_local], NC
-        )  # NC sentinel
-        h_rr = jnp.where(sendh, (pick_local + 1) % self.CPH, h_rr)
-        # seq selection: retransmissions first
-        pick_cc = jnp.clip(pick_conn, 0, NC - 1)
-        use_rtx = c_rtx_count[pick_cc] > 0
-        rtx_rows = self._bm_rows(c_rtx, pick_cc, conn_axis)  # (NH, MSG)
-        rtx_seq = jnp.argmax(rtx_rows, axis=1).astype(jnp.int32)
-        new_seq = c_next_new[pick_cc]
-        seq = jnp.where(use_rtx, rtx_seq, new_seq)
-        c_rtx = self._bm_set_false(
-            c_rtx, jnp.where(sendh & use_rtx, pick_conn, NC), rtx_seq, conn_axis
-        )
-        # each host picks <= 1 conn and a conn lives on one host, so
-        # per-conn injection counts are 0/1: one stacked segment-sum covers
-        # the send mask, rtx/new splits and the inflight increment
-        isum = self._seg_sum_b(
-            pick_conn,
-            jnp.stack([
-                sendh.astype(jnp.int32),
-                (sendh & use_rtx).astype(jnp.int32),
-            ]),
-            NC + 1,
-        )
-        send_mask = isum[0, :NC] > 0
-        c_rtx_count = c_rtx_count - isum[1, :NC]
-        c_next_new = c_next_new + (isum[0] - isum[1])[:NC]
-        c_inflight = c_inflight + isum[0, :NC]
-        injected_d = n_alloc
-
-        # the load balancer stamps the EV (REPS Algorithm 2)
-        prev_lb = lb_state
-        evs, lb_state = self.lb.choose_ev(
-            lb_state, send_mask, jax.random.fold_in(key, 2), now
-        )
-        if emit_events:
-            lb_counts = lb_counts + self.lb.trace(
-                "choose", prev_lb, lb_state, send_mask
+        with jax.named_scope("tick.injection"):
+            started = (now >= scn.conn_start) & (
+                (scn.conn_dep < 0) | c_done[jnp.clip(scn.conn_dep, 0, NC - 1)]
             )
-        pkt_ev = evs[pick_cc]
+            has_work = (c_rtx_count > 0) | (c_next_new < scn.conn_msg)
+            can = (
+                started
+                & ~c_done
+                & has_work
+                & (c_inflight < jnp.floor(c_cwnd).astype(jnp.int32))
+            )
+            hc = scn.host_conns  # (NH, CPH)
+            elig = can[jnp.clip(hc, 0, NC - 1)] & (hc >= 0)
+            ordr = (jnp.arange(self.CPH)[None, :] - h_rr[:, None]) % self.CPH
+            score = jnp.where(elig, ordr, BIG)
+            pick_local = jnp.argmin(score, axis=1).astype(jnp.int32)
+            any_pick = jnp.min(score, axis=1) < BIG
+            # free-slot allocation (ring pop)
+            srank = jnp.cumsum(any_pick.astype(jnp.int32)) - 1
+            can_alloc = srank < fl_count
+            if sparse:
+                # active-set capacity gate.  Since every non-FREE slot is
+                # tracked, as_count + fl_count == NP always — so with A == NP
+                # this conjunct is exactly `srank < fl_count` again and the
+                # sparse path stays bit-identical to dense; when A binds, the
+                # overflow surfaces as counted alloc-fails, never lost slots.
+                can_alloc = can_alloc & (as_count + srank < self.A)
+            sendh = any_pick & can_alloc
+            alloc_fail_d = jnp.sum((any_pick & ~can_alloc).astype(jnp.int32))
+            n_alloc = jnp.sum(sendh.astype(jnp.int32))
+            slot_p = fl[(fl_head + srank) % NP]
+            fl_head = (fl_head + n_alloc) % NP
+            fl_count = fl_count - n_alloc
 
-        wslot = jnp.where(sendh, slot_p, NP)
-        W = jnp.stack([
-            jnp.full((NH,), FLYING, jnp.int32),  # PS
-            pick_conn,  # PCONN
-            pkt_ev,  # PEV
-            seq,  # PSEQ
-            jnp.zeros((NH,), jnp.int32),  # PHOP
-            jnp.full((NH,), -1, jnp.int32),  # PCURQ
-            jnp.full((NH,), now, jnp.int32),  # PSEND
-            jnp.full((NH,), now + cfg.hop_latency_ticks, jnp.int32),  # PEVT
-            jnp.zeros((NH,), jnp.int32),  # PECN
-            jnp.zeros((NH,), jnp.int32),  # PORPH
-            jnp.zeros((NH,), jnp.int32),  # PACK
-        ])
-        # one (PF, NH) block scatter writes the whole new-packet rows
-        pkt = pkt.at[:, wslot].set(W, mode="drop")
+            pick_conn = jnp.where(
+                sendh, hc[jnp.arange(NH), pick_local], NC
+            )  # NC sentinel
+            h_rr = jnp.where(sendh, (pick_local + 1) % self.CPH, h_rr)
+            # seq selection: retransmissions first
+            pick_cc = jnp.clip(pick_conn, 0, NC - 1)
+            use_rtx = c_rtx_count[pick_cc] > 0
+            rtx_rows = self._bm_rows(c_rtx, pick_cc, conn_axis)  # (NH, MSG)
+            rtx_seq = jnp.argmax(rtx_rows, axis=1).astype(jnp.int32)
+            new_seq = c_next_new[pick_cc]
+            seq = jnp.where(use_rtx, rtx_seq, new_seq)
+            c_rtx = self._bm_set_false(
+                c_rtx, jnp.where(sendh & use_rtx, pick_conn, NC), rtx_seq, conn_axis
+            )
+            # each host picks <= 1 conn and a conn lives on one host, so
+            # per-conn injection counts are 0/1: one stacked segment-sum covers
+            # the send mask, rtx/new splits and the inflight increment
+            isum = self._seg_sum_b(
+                pick_conn,
+                jnp.stack([
+                    sendh.astype(jnp.int32),
+                    (sendh & use_rtx).astype(jnp.int32),
+                ]),
+                NC + 1,
+            )
+            send_mask = isum[0, :NC] > 0
+            c_rtx_count = c_rtx_count - isum[1, :NC]
+            c_next_new = c_next_new + (isum[0] - isum[1])[:NC]
+            c_inflight = c_inflight + isum[0, :NC]
+            injected_d = n_alloc
+
+            with jax.named_scope("tick.lb"):
+                # the load balancer stamps the EV (REPS Algorithm 2)
+                prev_lb = lb_state
+                evs, lb_state = self.lb.choose_ev(
+                    lb_state, send_mask, jax.random.fold_in(key, 2), now
+                )
+                if emit_events:
+                    lb_counts = lb_counts + self.lb.trace(
+                        "choose", prev_lb, lb_state, send_mask
+                    )
+            pkt_ev = evs[pick_cc]
+
+            wslot = jnp.where(sendh, slot_p, NP)
+            W = jnp.stack([
+                jnp.full((NH,), FLYING, jnp.int32),  # PS
+                pick_conn,  # PCONN
+                pkt_ev,  # PEV
+                seq,  # PSEQ
+                jnp.zeros((NH,), jnp.int32),  # PHOP
+                jnp.full((NH,), -1, jnp.int32),  # PCURQ
+                jnp.full((NH,), now, jnp.int32),  # PSEND
+                jnp.full((NH,), now + cfg.hop_latency_ticks, jnp.int32),  # PEVT
+                jnp.zeros((NH,), jnp.int32),  # PECN
+                jnp.zeros((NH,), jnp.int32),  # PORPH
+                jnp.zeros((NH,), jnp.int32),  # PACK
+            ])
+            # one (PF, NH) block scatter writes the whole new-packet rows
+            pkt = pkt.at[:, wslot].set(W, mode="drop")
 
         # =============== 6. free-list push ==============================
-        # slots popped and re-used this tick are FLYING now, not FREE — no
-        # conflict with the push below.
-        if sparse:
-            fs_a = jnp.where(as_valid, pkt[PS, asx], FREE)  # post-tick states
-            freed_a = as_valid & (fs_a == FREE) & (entry_ps_a != FREE)
-            f_pos = self._compact(freed_a, self.MAX_FREE)
-            f_idx2 = jnp.where(
-                f_pos < self.A, as_idx[jnp.minimum(f_pos, self.A - 1)], NP
-            )
-        else:
-            freed = (pkt[PS] == FREE) & (state_at_entry != FREE)
-            f_idx2 = self._compact(freed, self.MAX_FREE)
-        f_val = f_idx2 < NP
-        n_freed = jnp.sum(f_val.astype(jnp.int32))
-        if self.MAX_FREE <= NP and not sparse:
-            # the push targets a contiguous (mod NP) ring segment, so it is
-            # a rotate + static-slice blend + rotate back — a scatter here
-            # would serialize over MAX_FREE rows per sweep lane on CPU/TPU
-            start = (fl_head + fl_count) % NP
-            rot = jnp.roll(fl, -start)
-            head = jnp.where(
-                jnp.arange(self.MAX_FREE, dtype=jnp.int32) < n_freed,
-                f_idx2,
-                rot[: self.MAX_FREE],
-            )
-            fl = jnp.roll(rot.at[: self.MAX_FREE].set(head), start)
-        else:
-            # positional scatter: O(MAX_FREE) instead of the O(NP) roll —
-            # always in sparse mode (that roll is exactly the dense cost
-            # the active set removes), or under a tiny pkt_slots pin.
-            # Both branches write identical fl contents.
-            frank = jnp.cumsum(f_val.astype(jnp.int32)) - 1
-            fpos = (fl_head + fl_count + frank) % NP
-            fl = fl.at[jnp.where(f_val, fpos, NP)].set(f_idx2, mode="drop")
-        fl_count = fl_count + n_freed
+        with jax.named_scope("tick.freelist"):
+            # slots popped and re-used this tick are FLYING now, not FREE — no
+            # conflict with the push below.
+            if sparse:
+                fs_a = jnp.where(as_valid, pkt[PS, asx], FREE)  # post-tick states
+                freed_a = as_valid & (fs_a == FREE) & (entry_ps_a != FREE)
+                f_pos = self._compact(freed_a, self.MAX_FREE)
+                f_idx2 = jnp.where(
+                    f_pos < self.A, as_idx[jnp.minimum(f_pos, self.A - 1)], NP
+                )
+            else:
+                freed = (pkt[PS] == FREE) & (state_at_entry != FREE)
+                f_idx2 = self._compact(freed, self.MAX_FREE)
+            f_val = f_idx2 < NP
+            n_freed = jnp.sum(f_val.astype(jnp.int32))
+            if self.MAX_FREE <= NP and not sparse:
+                # the push targets a contiguous (mod NP) ring segment, so it is
+                # a rotate + static-slice blend + rotate back — a scatter here
+                # would serialize over MAX_FREE rows per sweep lane on CPU/TPU
+                start = (fl_head + fl_count) % NP
+                rot = jnp.roll(fl, -start)
+                head = jnp.where(
+                    jnp.arange(self.MAX_FREE, dtype=jnp.int32) < n_freed,
+                    f_idx2,
+                    rot[: self.MAX_FREE],
+                )
+                fl = jnp.roll(rot.at[: self.MAX_FREE].set(head), start)
+            else:
+                # positional scatter: O(MAX_FREE) instead of the O(NP) roll —
+                # always in sparse mode (that roll is exactly the dense cost
+                # the active set removes), or under a tiny pkt_slots pin.
+                # Both branches write identical fl contents.
+                frank = jnp.cumsum(f_val.astype(jnp.int32)) - 1
+                fpos = (fl_head + fl_count + frank) % NP
+                fl = fl.at[jnp.where(f_val, fpos, NP)].set(f_idx2, mode="drop")
+            fl_count = fl_count + n_freed
 
-        if sparse:
-            # active-set maintenance: drop freed slots, add this tick's
-            # allocations (wslot), re-sort ascending.  Real entries ≤ A by
-            # the injection gate; NP sentinels sort to the tail.
-            alive = as_valid & (fs_a != FREE)
-            cand = jnp.concatenate([jnp.where(alive, as_idx, NP), wslot])
-            as_idx = jnp.sort(cand)[: self.A]
-            as_count = jnp.sum(alive.astype(jnp.int32)) + n_alloc
+            if sparse:
+                # active-set maintenance: drop freed slots, add this tick's
+                # allocations (wslot), re-sort ascending.  Real entries ≤ A by
+                # the injection gate; NP sentinels sort to the tail.
+                with jax.named_scope("tick.active_set"):
+                    alive = as_valid & (fs_a != FREE)
+                    cand = jnp.concatenate([jnp.where(alive, as_idx, NP), wslot])
+                    as_idx = jnp.sort(cand)[: self.A]
+                    as_count = jnp.sum(alive.astype(jnp.int32)) + n_alloc
 
-        # =============== 7. fused stats update ==========================
-        s_stats = s_stats + jnp.stack([
-            drops_cong_d, drops_fail_d, timeouts_d, delivered_d,
-            ecn_marks_d, injected_d, unprocessed, alloc_fail_d,
-        ])
+            # =============== 7. fused stats update ==========================
+            s_stats = s_stats + jnp.stack([
+                drops_cong_d, drops_fail_d, timeouts_d, delivered_d,
+                ecn_marks_d, injected_d, unprocessed, alloc_fail_d,
+            ])
 
         if conn_axis is not None:
             # conn-sharded exit: hand back only this device's block of the
             # gathered per-conn vectors (inverse of the entry all_gather —
             # every device computed the identical full-shape values).
-            def cslice(x):
-                return jax.lax.dynamic_slice_in_dim(x, coff, NCd, axis=0)
+            with jax.named_scope("tick.conn_exchange"):
+                def cslice(x):
+                    return jax.lax.dynamic_slice_in_dim(x, coff, NCd, axis=0)
 
-            (c_inflight, c_next_new, c_delivered, c_rx_pending, c_done,
-             c_done_tick, c_rtx_count, c_cwnd, c_alpha) = (
-                cslice(c_inflight), cslice(c_next_new),
-                cslice(c_delivered), cslice(c_rx_pending),
-                cslice(c_done), cslice(c_done_tick),
-                cslice(c_rtx_count), cslice(c_cwnd), cslice(c_alpha),
-            )
+                (c_inflight, c_next_new, c_delivered, c_rx_pending, c_done,
+                 c_done_tick, c_rtx_count, c_cwnd, c_alpha) = (
+                    cslice(c_inflight), cslice(c_next_new),
+                    cslice(c_delivered), cslice(c_rx_pending),
+                    cslice(c_done), cslice(c_done_tick),
+                    cslice(c_rtx_count), cslice(c_cwnd), cslice(c_alpha),
+                )
 
         new_state = SimState(
             pkt,
@@ -1631,7 +1644,8 @@ class Simulator:
         probe's (NC,) fields (done_now / fct) are per-device conn shards,
         consistent with the sharded carry."""
         new, _ = self.step_scenario(state, tick, base_key, scn, conn_axis=conn_axis)
-        return new, self.probe(state, new, tick, scn)
+        with jax.named_scope("tick.telemetry"):
+            return new, self.probe(state, new, tick, scn)
 
     def step_events(
         self,
@@ -1646,7 +1660,8 @@ class Simulator:
         new, _, events = self.step_scenario(
             state, tick, base_key, scn, emit_events=True, conn_axis=conn_axis
         )
-        return new, self.probe(state, new, tick, scn), events
+        with jax.named_scope("tick.telemetry"):
+            return new, self.probe(state, new, tick, scn), events
 
     # ------------------------------------------------------------------
     @functools.partial(jax.jit, static_argnums=(0, 1))

@@ -81,7 +81,6 @@ Example (one compiled call per shape bucket, not per cell):
 from __future__ import annotations
 
 import dataclasses
-import time
 from typing import Any, Sequence
 
 import jax
@@ -102,6 +101,7 @@ from repro.netsim.failures import truncate_dead
 from repro.netsim.metrics import RunSummary, summarize, summarize_sketch
 from repro.netsim.telemetry import TelemetrySpec
 from repro.netsim.tracer import TraceSpec
+from repro.utils.spans import span
 
 # padded conns start here: far beyond any sweep horizon, still well inside
 # int32 so `now >= start` arithmetic cannot wrap.
@@ -1235,21 +1235,24 @@ class SweepEngine:
                 if summary and trace is not None:
                     states, tel, trc = carry
                     new_states, probe, events = vstep(states, t, keys, scn)
-                    new_carry = (
-                        new_states,
-                        tel_update(tel, probe),
-                        trc_update(trc, probe, events),
-                    )
+                    with jax.named_scope("tick.telemetry"):
+                        new_carry = (
+                            new_states,
+                            tel_update(tel, probe),
+                            trc_update(trc, probe, events),
+                        )
                     tr = None
                 elif summary:
                     states, tel = carry
                     new_states, probe = vstep(states, t, keys, scn)
-                    new_carry = (new_states, tel_update(tel, probe))
+                    with jax.named_scope("tick.telemetry"):
+                        new_carry = (new_states, tel_update(tel, probe))
                     tr = None
                 else:
                     new_carry, tr = vstep(carry, t, keys, scn)
                 if masked:
-                    new_carry = freeze(t < horizon, new_carry, carry)
+                    with jax.named_scope("tick.freeze"):
+                        new_carry = freeze(t < horizon, new_carry, carry)
                 return new_carry, (tr if full else None)
 
             ticks = t0 + jnp.arange(n, dtype=jnp.int32)
@@ -1509,43 +1512,43 @@ class SweepEngine:
         if ticks % chunk:
             sizes.append(ticks % chunk)
 
-        t_c0 = time.time()
-        carry = self.bucket_carry(bucket, collect, spec, trace)
-        # AOT-compile each distinct chunk length (usually 1-2) untimed;
-        # sub-buckets of a split group share the compiled executables.
-        for n in sorted(set(sizes)):
-            self.chunk_runner(
-                bucket, n, collect, spec, example_carry=carry, trace=trace
-            )
-        if early_exit and prog.quiescent_fn is None:
-            prog.quiescent_fn = self._make_quiescent_fn(prog)
-        quiescent = prog.quiescent_fn if early_exit else None
-        jax.block_until_ready(jax.tree_util.tree_leaves(carry)[0])
-        bucket.compile_wall_s = time.time() - t_c0
+        with span("sweep.bucket.compile") as compiling:
+            carry = self.bucket_carry(bucket, collect, spec, trace)
+            # AOT-compile each distinct chunk length (usually 1-2) untimed;
+            # sub-buckets of a split group share the compiled executables.
+            for n in sorted(set(sizes)):
+                self.chunk_runner(
+                    bucket, n, collect, spec, example_carry=carry, trace=trace
+                )
+            if early_exit and prog.quiescent_fn is None:
+                prog.quiescent_fn = self._make_quiescent_fn(prog)
+            quiescent = prog.quiescent_fn if early_exit else None
+            jax.block_until_ready(jax.tree_util.tree_leaves(carry)[0])
+        bucket.compile_wall_s = compiling.seconds
 
         trace_chunks = []
         offset = 0
-        t_e0 = time.time()
-        for n in sizes:
-            carry, traces = self.run_chunk(
-                bucket, carry, offset, n, collect, spec, trace
-            )
-            offset += n
-            if collect == "full":
-                # stream this chunk to host so the device never holds more
-                # than `chunk` ticks of trace
-                trace_chunks.append(jax.device_get(traces))
-            states = carry[0] if summary else carry
-            if quiescent is not None and offset < ticks and bool(
-                quiescent(
-                    states, bucket.scn, jnp.asarray(bucket.horizons),
-                    jnp.asarray(offset, jnp.int32),
+        with span("sweep.bucket.exec") as executing:
+            for n in sizes:
+                carry, traces = self.run_chunk(
+                    bucket, carry, offset, n, collect, spec, trace
                 )
-            ):
-                break
-        states = carry[0] if summary else carry
-        jax.block_until_ready(states.c_done)
-        bucket.exec_wall_s = time.time() - t_e0
+                offset += n
+                if collect == "full":
+                    # stream this chunk to host so the device never holds more
+                    # than `chunk` ticks of trace
+                    trace_chunks.append(jax.device_get(traces))
+                states = carry[0] if summary else carry
+                if quiescent is not None and offset < ticks and bool(
+                    quiescent(
+                        states, bucket.scn, jnp.asarray(bucket.horizons),
+                        jnp.asarray(offset, jnp.int32),
+                    )
+                ):
+                    break
+            states = carry[0] if summary else carry
+            jax.block_until_ready(states.c_done)
+        bucket.exec_wall_s = executing.seconds
         self.finalize_bucket(
             bucket, carry, collect, offset, trace_chunks, spec, trace
         )

@@ -16,6 +16,7 @@ persistent compilation cache is off for this module: entries compiled for
 a described chip could never be read back.
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -125,10 +126,11 @@ def test_serial_simulator_program_holds_mosaic_kernels(one_chip):
         assert any(kernel in line for line in mosaic), kernel
 
 
-def test_sweep_chunk_program_holds_mosaic_kernels(one_chip):
-    """One bucket's summary-mode chunk program (the sweep path every
-    figure grid runs: ECMP/OPS/REPS rows under a vmapped LB switch, with
-    the telemetry fold) compiles with its kernels batched over rows."""
+@pytest.fixture(scope="module")
+def sweep_chunk_text(one_chip):
+    """Compiled HLO text of one bucket's summary-mode chunk program (the
+    sweep path every figure grid runs: ECMP/OPS/REPS rows under a vmapped
+    LB switch, with the telemetry fold) for the described chip."""
     from repro.configs.arcane_paper import FATTREE_128
     from repro.netsim import SweepCase, SweepEngine, TelemetrySpec, workloads
 
@@ -151,7 +153,38 @@ def test_sweep_chunk_program_holds_mosaic_kernels(one_chip):
         jnp.asarray(bucket.horizons), jnp.zeros((), jnp.int32),
     )
     fn = eng._make_chunk_fn(bucket.program, 8, "summary", spec)
-    compiled = fn.lower(*jax.tree_util.tree_map(
+    return fn.lower(*jax.tree_util.tree_map(
         lambda x: _sds(one_chip, x.shape, x.dtype), args
-    )).compile()
-    assert "tpu_custom_call" in compiled.as_text()
+    )).compile().as_text()
+
+
+def test_sweep_chunk_program_holds_mosaic_kernels(sweep_chunk_text):
+    """The chunk program compiles with its kernels batched over rows."""
+    assert "tpu_custom_call" in sweep_chunk_text
+
+
+def test_sweep_chunk_program_keeps_tick_scopes(sweep_chunk_text):
+    """Every tick stage's ``jax.named_scope`` survives the TPU compile in
+    the ops' metadata, where a profiler trace's ops are mapped to stages;
+    the Mosaic kernels keep their wrappers' names inside their stages."""
+    op_names = re.findall(r'op_name="([^"]*)"', sweep_chunk_text)
+
+    def stages_of(ops):  # the innermost tick.* scope of each op
+        found = (re.findall(r"(?<![\w.])tick\.[a-z_]+", op) for op in ops)
+        return {f[-1] for f in found if f}
+
+    stages = stages_of(op_names)
+    assert stages == {
+        "tick.feedback", "tick.rto", "tick.service", "tick.arrivals",
+        "tick.injection", "tick.freelist", "tick.lb", "tick.telemetry",
+    }
+    kernels = {
+        kernel: stages_of(op for op in op_names if f"jit({kernel})" in op)
+        for kernel in ("seg_rank_pallas", "seg_sum_pallas",
+                       "queue_tick_pallas", "reps_tick_pallas")
+    }
+    assert kernels["queue_tick_pallas"] == {"tick.arrivals"}
+    assert "tick.feedback" in kernels["seg_rank_pallas"]
+    assert {"tick.feedback", "tick.rto", "tick.service",
+            "tick.injection"} <= kernels["seg_sum_pallas"]
+    assert kernels["reps_tick_pallas"] == {"tick.lb"}
