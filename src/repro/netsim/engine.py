@@ -1001,7 +1001,6 @@ class Simulator:
             with jax.named_scope("tick.active_set"):
                 asx = jnp.minimum(as_idx, NP - 1)
                 as_valid = as_idx < NP
-                asg = jnp.where(as_valid, as_idx, NP)  # scatter-drop form
                 entry_ps_a = jnp.where(as_valid, pkt[PS, asx], FREE)
         else:
             state_at_entry = pkt[PS]
@@ -1110,45 +1109,38 @@ class Simulator:
 
         # =============== 2. RTO ========================================
         with jax.named_scope("tick.rto"):
-            # A packet fires its RTO exactly at send_tick + rto_ticks (send_tick
-            # is set once at injection and eligibility blockers — orphan, conn
-            # done — are permanent), and injection admits ≤ 1 packet per host
-            # per tick, so ≤ NH packets fire per tick: compact to NH rows and
-            # keep every scatter narrow instead of full packet-table width.
+            # A packet's RTO can fire only at send_tick + rto_ticks: PSEND is
+            # written once, at injection; orphaning and the live states
+            # (FLYING, QUEUED, LOST_WAIT) never come back once left; c_done
+            # never clears; and a row's ticks run contiguously.  A live,
+            # unorphaned packet older than that was checked at that tick and
+            # kept only because its connection was done, which it still is.
+            # So the candidates are the packets sent exactly rto_ticks ago
+            # (the first check after injection when rto_ticks < 1): at most
+            # NH, since injection admits <= 1 packet per host per tick.  The
+            # done-check and every write then stay NH rows wide.
+            rto_age = max(int(cfg.rto_ticks), 1)
             if sparse:
-                ps_a = jnp.where(due_a, FREE, ps_a)  # free feedback slots
-                porph_a = pkt[PORPH, asx] == 1
-                active_a = (ps_a == FLYING) | (ps_a == QUEUED) | (ps_a == LOST_WAIT)
-                cdone_a = c_done[jnp.clip(pkt[PCONN, asx], 0, NC - 1)]
-                rto_a = (
-                    active_a
-                    & ~porph_a
-                    & ((now - pkt[PSEND, asx]) >= cfg.rto_ticks)
-                    & ~cdone_a
-                    & as_valid
+                cand_a = (
+                    as_valid
+                    & ((ps_a == FLYING) | (ps_a == QUEUED) | (ps_a == LOST_WAIT))
+                    & (pkt[PORPH, asx] != 1)
+                    & ((now - pkt[PSEND, asx]) == rto_age)
                 )
-                r_pos = self._compact(rto_a, NH)
-                r_idx = jnp.where(
-                    r_pos < self.A, as_idx[jnp.minimum(r_pos, self.A - 1)], NP
+                c_pos = self._compact(cand_a, NH)
+                c_idx = jnp.where(
+                    c_pos < self.A, as_idx[jnp.minimum(c_pos, self.A - 1)], NP
                 )
-                timeouts_d = jnp.sum(rto_a.astype(jnp.int32))
             else:
-                # free all feedback slots
-                p_state = jnp.where(due, FREE, p_state)
-                p_conn = pkt[PCONN]
-                p_orphan = pkt[PORPH] == 1
-                active_data = (p_state == FLYING) | (p_state == QUEUED) | (p_state == LOST_WAIT)
-                conn_done_of_pkt = c_done[jnp.clip(p_conn, 0, NC - 1)]
-                rto = (
-                    active_data
-                    & ~p_orphan
-                    & ((now - pkt[PSEND]) >= cfg.rto_ticks)
-                    & ~conn_done_of_pkt
+                cand = (
+                    ((p_state == FLYING) | (p_state == QUEUED) | (p_state == LOST_WAIT))
+                    & (pkt[PORPH] != 1)
+                    & ((now - pkt[PSEND]) == rto_age)
                 )
-                r_idx = self._compact(rto, NH)
-                timeouts_d = jnp.sum(rto.astype(jnp.int32))
-            r_valid = r_idx < NP
-            Rp = pkt[:, jnp.minimum(r_idx, NP - 1)]  # (PF, NH)
+                c_idx = self._compact(cand, NH)
+            Rp = pkt[:, jnp.minimum(c_idx, NP - 1)]  # (PF, NH)
+            r_valid = (c_idx < NP) & ~c_done[jnp.clip(Rp[PCONN], 0, NC - 1)]
+            timeouts_d = jnp.sum(r_valid.astype(jnp.int32))
             r_conn = jnp.where(r_valid, Rp[PCONN], NC)
             r_seq = jnp.where(r_valid, Rp[PSEQ], 0)
             rcv_already = self._bm_get(c_rcv, r_conn, r_seq, conn_axis)
@@ -1180,19 +1172,13 @@ class Simulator:
                     lb_counts = lb_counts + self.lb.trace(
                         "timeout", prev_lb, lb_state, rto_per_conn > 0
                     )
-            # orphan in-network packets; free LOST_WAIT ones — write the two
-            # packet columns (state / orphan) back once (active rows only in
-            # sparse mode; untracked slots are FREE and untouched either way)
-            if sparse:
-                porph_a = porph_a | rto_a
-                ps_a = jnp.where(rto_a & (ps_a == LOST_WAIT), FREE, ps_a)
-                pkt = pkt.at[PS, asg].set(ps_a, mode="drop")
-                pkt = pkt.at[PORPH, asg].set(porph_a.astype(jnp.int32), mode="drop")
-            else:
-                p_orphan = p_orphan | rto
-                p_state = jnp.where(rto & (p_state == LOST_WAIT), FREE, p_state)
-                pkt = pkt.at[PS].set(p_state)
-                pkt = pkt.at[PORPH].set(p_orphan.astype(jnp.int32))
+            # free the feedback slots; orphan the fired packets and free the
+            # LOST_WAIT ones: one whole-row scatter at <= MAX_EV + NH slots
+            Rn = Rp.at[PS].set(jnp.where(Rp[PS] == LOST_WAIT, FREE, Rp[PS]))
+            Rn = Rn.at[PORPH].set(1)
+            pkt = pkt.at[:, jnp.concatenate([e_idx, jnp.where(r_valid, c_idx, NP)])].set(
+                jnp.concatenate([E.at[PS].set(FREE), Rn], axis=1), mode="drop"
+            )
 
         # =============== 3. service / dequeue ===========================
         with jax.named_scope("tick.service"):
