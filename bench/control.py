@@ -41,7 +41,7 @@ def main(argv=None) -> int:
         grid = harness.build_grid(spec, seed)
         numbers, rows = reference.control(
             grid.cfg, grid.inputs, harness.row_index(grid), args.ticks,
-            grid.collect, grid.chunk, spec.traffic["ticks"],
+            grid.collect, grid.chunk, spec.traffic["ticks"], model=grid.model,
         )
         ok = rows > 0 and all(v["value"] <= v["limit"]
                               for v in numbers.values())

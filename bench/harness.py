@@ -1,9 +1,11 @@
 """The harness: a cell from its files, the measured window, the result line.
 
 A cell of ``BENCHMARK.json`` names a configuration (``bench/configs/``) and a
-traffic mix (``bench/traffic/``); ``load_cell`` finds both by name, and
-``bench/metrics/<metric>.py`` holds the reader of each per-layer metric.
-Nothing here names a cell, so a later cell needs only new files and entries.
+traffic mix (``bench/traffic/``); ``load_cell`` finds both by name.  The code
+those files name (the traffic's generator kind, the failure regime, the
+reference model) and the reader of each per-layer metric are modules found
+by name too (``bench/extensions.py``).  Nothing here names a cell, a kind or
+a deployment, so a later one needs only new files and entries.
 
 The window drives the sweep path as a user's grid runs it, through
 ``SweepEngine``'s resumable API (the one the soak runtime drives):
@@ -11,15 +13,17 @@ The window drives the sweep path as a user's grid runs it, through
 engine's quiescence poll at every chunk boundary, ``finalize_bucket`` when
 a bucket reaches its horizon or quiesces, then the same grid again from its
 initial state.  The window ends at the first chunk boundary after
-``seconds``.  Host spans ``bench.chunk`` / ``bench.poll`` / ``bench.regrid``
-name what the host is doing, so a trace can attribute device idle time.
+``seconds``, not counting the time the profiler takes to start and stop
+in a traced run.  Host spans ``bench.chunk`` / ``bench.poll`` /
+``bench.regrid`` name what the host is doing, so a trace can attribute
+device idle time.
 """
 from __future__ import annotations
 
 import dataclasses
+import functools
 import gc
 import glob
-import importlib.util
 import json
 import pathlib
 import time
@@ -29,7 +33,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.profiler import TraceAnnotation
 
-from bench import generate, kernel_work, reference, trace_reduce
+from bench import extensions, generate, kernel_work, reference, trace_reduce
 from repro.netsim.config import SimConfig
 from repro.netsim.sweep import SweepCase, SweepEngine
 from repro.netsim.telemetry import TelemetrySpec
@@ -48,6 +52,7 @@ class CellSpec:
     traffic: dict
     end_to_end: list  # metric entries this cell reports without a trace
     per_layer: list  # metric entries this cell reports with a trace
+    root: pathlib.Path = ROOT  # the checkout whose bench/ holds its modules
 
 
 def load_cell(name: str, root: pathlib.Path = ROOT) -> CellSpec:
@@ -79,16 +84,13 @@ def cell_from_files(name: str, chips: int, config_file: str, traffic: str,
         ),
         end_to_end=mine(bench["end_to_end"]),
         per_layer=mine(bench["per_layer"]),
+        root=root,
     )
 
 
 def metric_reader(name: str, root: pathlib.Path = ROOT):
     """The ``read(ctx)`` function of ``bench/metrics/<name>.py``."""
-    path = root / "bench" / "metrics" / f"{name}.py"
-    spec = importlib.util.spec_from_file_location(f"bench_metric_{name}", path)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod.read
+    return extensions.find(root, "metrics", name).read
 
 
 # ---------------------------------------------------------------------------
@@ -105,6 +107,7 @@ class Grid:
     tel_spec: TelemetrySpec | None
     chunk: int
     early_exit: bool
+    model: object  # the reference model of ``correct`` (reference.model_of)
     quiescent: dict = dataclasses.field(default_factory=dict)  # group -> fn
 
     def sizes(self, bucket) -> list[int]:
@@ -117,7 +120,7 @@ def build_grid(spec: CellSpec, seed: int) -> Grid:
     """The cell's engine and inputs for ``seed`` (nothing compiled yet)."""
     cfg = SimConfig(**spec.config["sim"])
     tr = spec.traffic
-    inputs = generate.build(spec.config, tr, cfg, seed)
+    inputs = generate.build(spec.config, tr, cfg, seed, spec.root)
     cases = [
         SweepCase(
             f"{spec.name}/{lb['lb']}", inputs.workload, lb["lb"], tr["ticks"],
@@ -135,6 +138,7 @@ def build_grid(spec: CellSpec, seed: int) -> Grid:
         engine=engine, collect=collect,
         tel_spec=TelemetrySpec.default() if collect == "summary" else None,
         chunk=int(tr["chunk_ticks"]), early_exit=bool(tr["early_exit"]),
+        model=reference.model_of(spec.config, spec.root),
     )
 
 
@@ -195,6 +199,8 @@ class Snapshot:
     ticks: int  # ticks the rows advanced (a frozen row stopped earlier)
     state: object  # host SimState, leaves (rows, ...)
     telemetry: object  # host (rows, size) int32 or None
+    finalized: bool  # the program finished the bucket here (horizon or
+    # early exit); else the window closed with it in flight
 
 
 @dataclasses.dataclass
@@ -205,8 +211,18 @@ class Window:
     rows_lost: int  # rows whose state reports lost simulator work
     chunks: int
     compiles: int  # backend compiles inside the window (should be 0)
-    snapshots: dict  # bucket index -> the latest Snapshot
+    # (bucket index, Snapshot): each bucket's first finalize (a re-grid
+    # repeats it), then the bucket in flight at the close
+    snapshots: list
     traced: dict | None = None  # the traced chunk: ticks, rows
+    # each finalize: (bucket index, tick it stopped at, seconds into window)
+    regrids: list = dataclasses.field(default_factory=list)
+    # host seconds of each finalize, from the poll that found the bucket
+    # done to the re-grid's fresh carry on the device (``bench.regrid``)
+    regrid_s: list = dataclasses.field(default_factory=list)
+    # host seconds of each pass of the loop: chunk, poll and any re-grid
+    chunk_s: list = dataclasses.field(default_factory=list)
+    gc_runs: tuple = ()  # Python GC collections per generation in it
 
 
 class _CompileCounter:
@@ -247,40 +263,56 @@ def lost_rows(state, n_rows: int) -> int:
     return int(np.sum((s[:, ST_UNPROC] > 0) | (s[:, ST_ALLOC_FAIL] > 0)))
 
 
+def row_ticks_run(bucket, offset: int) -> int:
+    """Row-ticks the bucket's rows simulated in its first ``offset`` ticks:
+    a row past its own horizon is frozen, and the ticks that early exit
+    skips after quiescence are not simulated (how soon a bucket quiesces
+    is the simulated network's, which the program may not change)."""
+    return int(np.sum(np.minimum(bucket.horizons[: bucket.n_rows], offset)))
+
+
 def run_window(grid: Grid, carry, seconds: float, trace_dir: str | None = None,
-               chunk_hook=None) -> Window:
+               chunk_hook=None, clock=time.perf_counter) -> Window:
     """Drive the grid for ``seconds`` (to the next chunk boundary).
 
     With ``trace_dir`` the window's second chunk with its poll (the first
     when ``seconds`` is 0) runs under the profiler, inside the host span
-    ``bench.traced``, and the window lasts at least that long.
-    ``chunk_hook(carry, bucket, t0, n) -> carry``, when given, replaces
-    ``run_chunk`` (the tests plant faults with it)."""
+    ``bench.traced``, and the window lasts at least that long; the seconds
+    the profiler takes to start and to stop do not count toward
+    ``seconds``, so a traced window holds the chunks, finalizes and
+    re-grids of an untraced one.  ``chunk_hook(carry, bucket, t0, n) ->
+    carry``, when given, replaces ``run_chunk`` (the tests plant faults
+    with it); ``clock`` is the window's clock in seconds."""
     eng = grid.engine
     run_chunk = chunk_hook or (
         lambda c, b, t0, n: eng.run_chunk(b, c, t0, n, grid.collect,
                                           grid.tel_spec)[0]
     )
     buckets = eng.buckets
-    snapshots: dict = {}
+    snapshots: list = []
     credit = lost = chunks = 0
     rows_started = buckets[0].n_rows
     bi, offset, k = 0, 0, 0
     sizes = grid.sizes(buckets[0])
     trace_at = None if trace_dir is None else (1 if seconds > 0 else 0)
     traced = None
+    regrids, regrid_s, chunk_s = [], [], []
+    paused = 0.0  # the profiler's start and stop
     counter = _CompileCounter.get()
     counter.on = True
-    t0 = time.perf_counter()
+    gc_before = [g["collections"] for g in gc.get_stats()]
+    t0 = last = clock()
     while True:
         bucket = buckets[bi]
         n = sizes[k]
         if chunks == trace_at:
+            t_start = clock()
             jax.profiler.start_trace(trace_dir)
             span = TraceAnnotation("bench.traced")
             span.__enter__()
             traced = {"ticks": n, "rows": bucket.n_rows,
                       "lb_rows": lb_rows(bucket)}
+            paused += clock() - t_start
         with TraceAnnotation("bench.chunk"):
             carry = run_chunk(carry, bucket, offset, n)
         offset += n
@@ -293,12 +325,15 @@ def run_window(grid: Grid, carry, seconds: float, trace_dir: str | None = None,
                 jax.block_until_ready(_states(grid, carry).c_done)
                 done = offset >= bucket.ticks
         if done:
+            t_done = clock()
+            regrids.append((bi, offset, t_done - t0))
             with TraceAnnotation("bench.regrid"):
                 eng.finalize_bucket(bucket, carry, grid.collect, offset,
                                     spec=grid.tel_spec)
-                snapshots[bi] = Snapshot(offset, bucket.final_state,
-                                         bucket.telemetry)
-                credit += int(np.sum(bucket.horizons[: bucket.n_rows]))
+                if bi not in {b for b, _ in snapshots}:
+                    snapshots.append((bi, Snapshot(
+                        offset, bucket.final_state, bucket.telemetry, True)))
+                credit += row_ticks_run(bucket, offset)
                 lost += lost_rows(bucket.final_state, bucket.n_rows)
                 bi = (bi + 1) % len(buckets)
                 bucket = buckets[bi]
@@ -307,28 +342,36 @@ def run_window(grid: Grid, carry, seconds: float, trace_dir: str | None = None,
                 rows_started += bucket.n_rows
                 sizes = grid.sizes(bucket)
                 offset, k = 0, 0
+            regrid_s.append(clock() - t_done)
         if chunks - 1 == trace_at:
+            t_stop = clock()
             span.__exit__(None, None, None)
             jax.profiler.stop_trace()
-        if time.perf_counter() - t0 >= seconds and (
+            paused += clock() - t_stop
+        now = clock()
+        chunk_s.append(now - last)
+        last = now
+        if now - t0 - paused >= seconds and (
             trace_at is None or chunks > trace_at
         ):
             break
-    elapsed = time.perf_counter() - t0
+    elapsed = last - t0
     counter.on = False
+    gc_runs = tuple(g["collections"] - b
+                    for g, b in zip(gc.get_stats(), gc_before))
     if offset:
-        credit += int(np.sum(np.minimum(bucket.horizons[: bucket.n_rows],
-                                        offset)))
+        credit += row_ticks_run(bucket, offset)
         host = jax.device_get(carry)
         st = jax.tree_util.tree_map(lambda x: x[: bucket.n_rows],
                                     _states(grid, host))
         lost += lost_rows(st, bucket.n_rows)
-        snapshots[bi] = Snapshot(
+        snapshots.append((bi, Snapshot(
             offset, st,
             host[1][: bucket.n_rows] if grid.collect == "summary" else None,
-        )
+            False,
+        )))
     return Window(elapsed, credit, rows_started, lost, chunks, counter.n,
-                  snapshots, traced)
+                  snapshots, traced, regrids, regrid_s, chunk_s, gc_runs)
 
 
 # ---------------------------------------------------------------------------
@@ -380,8 +423,14 @@ def run(spec: CellSpec, seed: int, seconds: float, trace: bool,
                         trace_dir=trace_dir if trace else None,
                         chunk_hook=chunk_hook)
     del carry
+    slow = int(np.argmax(window.chunk_s))
     log(f"window: {window.seconds:.3f} s, {window.chunks} chunks, "
-        f"{window.row_ticks} row-ticks, compiles inside: {window.compiles}")
+        f"{window.row_ticks} row-ticks, compiles inside: {window.compiles}, "
+        f"finalized (bucket, tick, s): {window.regrids}, re-grid s: "
+        f"{window.regrid_s}, slowest pass {slow}: "
+        f"{window.chunk_s[slow]:.4f} s (median "
+        f"{float(np.median(window.chunk_s)):.4f}), GC runs per "
+        f"generation: {window.gc_runs}")
     used = devices[: spec.chips]
     peak = memory_peak(used)
     index = row_index(grid)
@@ -391,13 +440,14 @@ def run(spec: CellSpec, seed: int, seconds: float, trace: bool,
         for fn in prog.chunk_fns.values()
     ) if trace else None
     inputs, cfg, collect, chunk = grid.inputs, grid.cfg, grid.collect, grid.chunk
+    model = grid.model
     del grid
     gc.collect()
 
     t_ref = time.time()
     numbers, compared = reference.check(
         cfg, inputs, index, window.snapshots, collect, chunk,
-        spec.traffic["ticks"],
+        spec.traffic["ticks"], model=model,
     )
     log(f"reference: {compared} rows in {time.time() - t_ref:.3f} s")
     correct = compared > 0 and all(
@@ -409,12 +459,8 @@ def run(spec: CellSpec, seed: int, seconds: float, trace: bool,
         "failed": window.rows_lost,
     }
     if trace:
-        ctx = {
-            "trace": None, "window": window,
-            "kernel_work": kernel_work.bucket_tick(
-                cfg, n_conns, window.traced["lb_rows"]),
-            "device_kind": devices[0].device_kind, "n_devices": spec.chips,
-        }
+        ctx = trace_context(cfg, n_conns, window, devices[0].device_kind,
+                            spec.chips)
         metrics, breakdown, dev_extra = traced_metrics(spec, ctx, trace_dir,
                                                        op_names)
     else:
@@ -431,9 +477,25 @@ def run(spec: CellSpec, seed: int, seconds: float, trace: bool,
         out["breakdown"] = breakdown
     out["window"] = {"seconds": window.seconds, "chunks": window.chunks,
                      "row_ticks": window.row_ticks,
-                     "compiles": window.compiles}
+                     "compiles": window.compiles,
+                     "regrids": window.regrids, "regrid_s": window.regrid_s,
+                     "chunk_s": window.chunk_s, "gc_runs": window.gc_runs}
     out["checks"] = numbers
     return out
+
+
+def trace_context(cfg, n_conns: int, window: Window, device_kind: str,
+                  n_devices: int) -> dict:
+    """What the per-layer readers read besides the trace (``ctx["trace"]``,
+    set by ``traced_metrics``).  ``ctx["kernel_work"]()`` counts the
+    kernels' work in one tick of the traced bucket on its first call: only
+    the roofline readers ask, and it is counted for 2-tier trees alone."""
+    return {
+        "trace": None, "window": window,
+        "kernel_work": functools.cache(lambda: kernel_work.bucket_tick(
+            cfg, n_conns, window.traced["lb_rows"])),
+        "device_kind": device_kind, "n_devices": n_devices,
+    }
 
 
 def traced_metrics(spec: CellSpec, ctx: dict, trace_dir: str, op_names):
@@ -447,7 +509,7 @@ def traced_metrics(spec: CellSpec, ctx: dict, trace_dir: str, op_names):
     ctx["trace"] = summary
     metrics = {}
     for m in spec.per_layer:
-        value = metric_reader(m["name"])(ctx)
+        value = metric_reader(m["name"], spec.root)(ctx)
         if value is not None:
             metrics[m["name"]] = {"value": value, "unit": m["unit"]}
     breakdown = {"device_ops": summary.top_ops(10),

@@ -149,7 +149,7 @@ def roofline(ctx: dict, kernel: str):
     least time of its work there over its device time, summed over the
     chips used, so work that every chip repeats counts once.  ``None``
     where the kernel did no counted work or did not run."""
-    work = ctx["kernel_work"].get(kernel)
+    work = ctx["kernel_work"]().get(kernel)
     ns = sum(d.kernel_by.get(kernel, 0.0)
              for d in ctx["trace"].devices.values())
     if work is None or work.bytes == 0 or ns <= 0:

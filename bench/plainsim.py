@@ -84,35 +84,6 @@ class Shapes:
         return 2 * self.T * self.U
 
 
-def shapes_of(cfg, src: np.ndarray, msg: np.ndarray) -> Shapes:
-    if cfg.tiers != 2 or cfg.fabric:
-        raise NotImplementedError("the reference models the 2-tier fat tree")
-    NH, H, U = cfg.n_hosts, cfg.hosts_per_tor, cfg.uplinks_per_tor
-    T = NH // H
-    NC = len(src)
-    NP = int(cfg.pkt_slots) or _pow2_at_least(
-        NC * cfg.max_cwnd_pkts + 4 * NH + 64)
-    A = NP
-    if cfg.conn_sharding:
-        # scale mode budgets slots by lifetime, not by window: a slot is
-        # freed within an RTO, the ACK and NACK returns and a full drain
-        # of the 3-hop path (a full queue at half rate per hop); hosts
-        # admit one packet a tick each
-        life = (cfg.rto_ticks + cfg.ack_delay_ticks + cfg.nack_delay_ticks
-                + 3 * (cfg.hop_latency_ticks + 2 * cfg.queue_capacity))
-        bound = _pow2_at_least(NH * life + 4 * NH + 64)
-        NP = int(cfg.pkt_slots) or min(NP, bound)
-        A = min(int(cfg.active_slots) or bound, NP)
-    msg_auto = min(cfg.max_msg_pkts, _pow2_at_least(int(msg.max())))
-    cph = int(np.bincount(src, minlength=NH).max())
-    return Shapes(
-        NH=NH, NC=NC, NQ=2 * T * U + NH,
-        NP=NP, A=A,
-        MSG=int(cfg.msg_slots) or msg_auto,
-        CPH=max(int(cfg.conns_per_host), cph), T=T, H=H, U=U,
-    )
-
-
 def first_set(mask, k: int):
     """The first ``k`` set positions of ``mask`` in ascending order, padded
     with ``len(mask)``: where the running count of set bits reaches 1, 2,
@@ -248,12 +219,6 @@ class Reps(Ecmp):
 LBS = {"ecmp": Ecmp, "ops": Ops, "reps": Reps}
 
 
-def make_lb(name: str, kwargs: dict):
-    if name not in LBS:
-        raise NotImplementedError(f"the reference has no load balancer {name!r}")
-    return LBS[name](**kwargs)
-
-
 # ---------------------------------------------------------------------------
 # The summary sketch
 # ---------------------------------------------------------------------------
@@ -270,7 +235,7 @@ class Sketch:
     the first failure drop, and the first timeout and delivery after it.
     ``flat`` lays it out word for word as the program's sketch does."""
 
-    def __init__(self, horizon: int, qcap: int, n_watch: int,
+    def __init__(self, horizon: int, qcap: int, n_watch: int, n_stats: int,
                  fct_bins: int = 64, qlen_bins: int = 32, windows: int = 24):
         def edges(hi, n):
             return np.geomspace(1.0, float(max(hi, 2)), n + 1).astype(np.float32)
@@ -280,13 +245,14 @@ class Sketch:
         self.stride = max(1, -(-horizon // windows))
         self.nw = -(-horizon // self.stride)
         self.nwatch = n_watch
+        self.nstats = n_stats
 
     def init(self):
         z = jnp.zeros((), jnp.int32)
         nb_f, nb_q = len(self.fct_edges) - 1, len(self.qlen_edges) - 1
         zs = lambda *s: jnp.zeros(s, jnp.int32)  # noqa: E731
         return dict(
-            totals=zs(len(STATS)),
+            totals=zs(self.nstats),
             fct_count=z, fct_sum_hi=z, fct_sum_lo=z,
             fct_min=jnp.int32(BIG), fct_max=jnp.int32(-1),
             done_tick_max=jnp.int32(-1), qlen_max=z, qlen_sum_hi=z,
@@ -294,7 +260,7 @@ class Sketch:
             fct_hi=zs(nb_f), fct_lo=zs(nb_f), qlen_hi=zs(nb_q),
             qlen_lo=zs(nb_q),
             util=zs(self.nw, self.nwatch), wqlen=zs(self.nw, self.nwatch),
-            wstats=zs(self.nw, len(STATS)),
+            wstats=zs(self.nw, self.nstats),
             first_drop=jnp.int32(BIG), first_timeout=jnp.int32(BIG),
             first_redeliver=jnp.int32(BIG),
         )
@@ -362,16 +328,25 @@ class Sketch:
 # The row
 # ---------------------------------------------------------------------------
 class Row:
-    """One row: its sizes, scenario and load balancer; ``tick`` is pure."""
+    """One row: its sizes, scenario and load balancer; ``tick`` is pure.
+
+    A reference extension (``bench/references/<name>.py``) subclasses it and
+    overrides what its deployment needs: the sizes (``shapes_of``), the
+    next-hop rule (``_next_queue``), the load balancers (``LBS``), the
+    failure kinds (``FAILURE_KINDS``, with ``_links_down``), what else it
+    refuses (``_check``), the counters (``STATS``) and, where its state
+    holds more, when a row is quiescent (``quiescent``)."""
+
+    STATS = STATS
+    LBS = LBS
+    FAILURE_KINDS = (0,)  # links down
 
     def __init__(self, cfg, workload, failures, seed: int, lb: str,
                  lb_kwargs: dict, horizon: int, collect: str,
                  control: bool = False):
-        if cfg.trimming or cfg.cc != "dctcp":
-            raise NotImplementedError("the reference models DCTCP without "
-                                      "trimming")
+        self._check(cfg, failures, lb)
         src = np.asarray(workload.src, np.int64)
-        self.sh = sh = shapes_of(cfg, src, np.asarray(workload.msg_pkts))
+        self.sh = sh = self.shapes_of(cfg, src, np.asarray(workload.msg_pkts))
         self.c = cfg
         hc = np.full((sh.NH, sh.CPH), -1, np.int32)
         fill = np.zeros(sh.NH, np.int64)
@@ -380,8 +355,6 @@ class Row:
             fill[h] += 1
         f = failures
         if f is not None and len(f.queue):
-            if np.any(np.asarray(f.kind) != 0):
-                raise NotImplementedError("the reference models links down")
             fq, fs, fe = f.queue, f.start, f.end
         else:
             fq = fs = fe = np.zeros((0,), np.int32)
@@ -395,10 +368,74 @@ class Row:
             f_e=i32(fe), key=jax.random.PRNGKey(seed),
         )
         self.watch = np.arange(min(cfg.n_watch_queues, sh.U), dtype=np.int32)
-        self.lb = make_lb(lb, {"evs_size": cfg.evs_size, **lb_kwargs})
-        self.sketch = (Sketch(horizon, cfg.queue_capacity, len(self.watch))
+        self.lb = self.LBS[lb](**{"evs_size": cfg.evs_size, **lb_kwargs})
+        self.sketch = (Sketch(horizon, cfg.queue_capacity, len(self.watch),
+                              len(self.STATS))
                        if collect == "summary" else None)
+        self.horizon = horizon
         self.control = control
+
+    def _check(self, cfg, failures, lb: str):
+        """Refuse what the row does not model."""
+        if cfg.trimming or cfg.cc != "dctcp":
+            raise NotImplementedError("the reference models DCTCP without "
+                                      "trimming")
+        if failures is not None and len(failures.queue) and not np.all(
+                np.isin(failures.kind, self.FAILURE_KINDS)):
+            raise NotImplementedError(
+                f"the reference models failure kinds {self.FAILURE_KINDS}")
+        if lb not in self.LBS:
+            raise NotImplementedError(
+                f"the reference has no load balancer {lb!r}")
+
+    def shapes_of(self, cfg, src: np.ndarray, msg: np.ndarray) -> Shapes:
+        """The row's sizes on the 2-tier fat tree; an extension on another
+        fabric overrides them with its next-hop rule (``_next_queue``)."""
+        if cfg.tiers != 2 or cfg.fabric:
+            raise NotImplementedError("the reference models the 2-tier "
+                                      "fat tree")
+        NH, H, U = cfg.n_hosts, cfg.hosts_per_tor, cfg.uplinks_per_tor
+        T = NH // H
+        NC = len(src)
+        NP = int(cfg.pkt_slots) or _pow2_at_least(
+            NC * cfg.max_cwnd_pkts + 4 * NH + 64)
+        A = NP
+        if cfg.conn_sharding:
+            # scale mode budgets slots by lifetime, not by window: a slot is
+            # freed within an RTO, the ACK and NACK returns and a full drain
+            # of the 3-hop path (a full queue at half rate per hop); hosts
+            # admit one packet a tick each
+            life = (cfg.rto_ticks + cfg.ack_delay_ticks + cfg.nack_delay_ticks
+                    + 3 * (cfg.hop_latency_ticks + 2 * cfg.queue_capacity))
+            bound = _pow2_at_least(NH * life + 4 * NH + 64)
+            NP = int(cfg.pkt_slots) or min(NP, bound)
+            A = min(int(cfg.active_slots) or bound, NP)
+        msg_auto = min(cfg.max_msg_pkts, _pow2_at_least(int(msg.max())))
+        cph = int(np.bincount(src, minlength=NH).max())
+        return Shapes(
+            NH=NH, NC=NC, NQ=2 * T * U + NH,
+            NP=NP, A=A,
+            MSG=int(cfg.msg_slots) or msg_auto,
+            CPH=max(int(cfg.conns_per_host), cph), T=T, H=H, U=U,
+        )
+
+    def quiescent(self, v: dict, t: int) -> bool:
+        """Whether the row whose ``view`` is ``v`` at tick ``t`` stays as it
+        is to its horizon: it is past the horizon, or no packet holds a slot
+        and no connection that can start before the horizon has work left.
+        Every later tick then serves, sends and counts nothing, and adds
+        nothing to the sketch.  Early exit may stop a bucket only where this
+        holds for each of its rows."""
+        if t >= self.horizon:
+            return True
+        start, dep, msg = (np.asarray(self.scn[k])
+                           for k in ("start", "dep", "msg"))
+        done = np.asarray(v["done"])
+        startable = (start < self.horizon) & (
+            (dep < 0) | done[np.clip(dep, 0, len(done) - 1)])
+        work = ((np.asarray(v["rtx_count"]) > 0)
+                | (np.asarray(v["next_new"]) < msg))
+        return len(v["packets"]) == 0 and not np.any(startable & ~done & work)
 
     # -- state ---------------------------------------------------------
     def init(self):
@@ -417,7 +454,7 @@ class Row:
             alpha=jnp.zeros((sh.NC,), jnp.float32),
             rr=zi(sh.NH), lb=self.lb.init(sh.NC, self.scn["key"]),
             fl=jnp.arange(sh.NP, dtype=jnp.int32), fl_head=jnp.int32(0),
-            fl_count=jnp.int32(sh.NP), stats=zi(len(STATS)),
+            fl_count=jnp.int32(sh.NP), stats=zi(len(self.STATS)),
         )
         return st, (self.sketch.init() if self.sketch else None)
 
@@ -443,6 +480,12 @@ class Row:
                          jnp.where(s_tor == d_tor, host_down, uplink),
                          jnp.where(on_uplink, spine_down, host_down))
 
+    def _links_down(self, scn, now):
+        """Queues whose link is down at ``now``: their head is lost."""
+        up = (now >= scn["f_s"]) & (now < scn["f_e"])
+        return jnp.zeros((self.sh.NQ,), bool).at[
+            jnp.where(up, scn["f_q"], self.sh.NQ)].set(True, mode="drop")
+
     def tick(self, st, t, scn):
         sh, c = self.sh, self.c
         NP, NC, NQ, NH = sh.NP, sh.NC, sh.NQ, sh.NH
@@ -451,7 +494,7 @@ class Row:
         key = jax.random.fold_in(scn["key"], t)
         p = dict(st["pkt"])
         entry_state = p["state"]
-        stats = dict.fromkeys(STATS, jnp.int32(0))
+        stats = dict.fromkeys(self.STATS, jnp.int32(0))
         cwnd, alpha, lb = st["cwnd"], st["alpha"], st["lb"]
         inflight, rtx, rcv = st["inflight"], st["rtx"], st["rcv"]
         rtx_count = st["rtx_count"]
@@ -506,9 +549,7 @@ class Row:
                                p["state"])
 
         # 3. service
-        up = (now >= scn["f_s"]) & (now < scn["f_e"])
-        down = jnp.zeros((NQ,), bool).at[jnp.where(up, scn["f_q"], NQ)].set(
-            True, mode="drop")
+        down = self._links_down(scn, now)
         q_len, q_head = st["q_len"], st["q_head"]
         serve = q_len > 0
         qid = jnp.arange(NQ)
@@ -656,7 +697,7 @@ class Row:
         fl = fl.at[jnp.where(fr_slot < NP, ring, NP)].set(fr_slot, mode="drop")
         fl_count = fl_count + n_freed
 
-        delta = jnp.stack([stats[k].astype(jnp.int32) for k in STATS])
+        delta = jnp.stack([stats[k].astype(jnp.int32) for k in self.STATS])
         if self.control:  # float state held in bfloat16
             cwnd, alpha = (jax.lax.reduce_precision(x, exponent_bits=8,
                                                     mantissa_bits=7)

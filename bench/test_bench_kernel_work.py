@@ -1,7 +1,10 @@
-"""The kernels' algorithmic work, counted from the paper fabric's shapes."""
+"""The kernels' algorithmic work, counted from the paper fabric's shapes,
+and only when a roofline reader asks for it."""
+import types
+
 import pytest
 
-from bench import kernel_work
+from bench import harness, kernel_work
 from repro.netsim.config import SimConfig
 
 NH = NC = 128  # hosts; a permutation has one conn per host
@@ -58,3 +61,22 @@ def test_least_time_is_bound_by_hbm():
 def test_unknown_device_kind_raises():
     with pytest.raises(KeyError, match="no peaks"):
         kernel_work.peaks("TPU v0 imaginary")
+
+
+def test_a_traced_run_counts_kernel_work_only_for_a_roofline_reader():
+    window = types.SimpleNamespace(
+        traced={"ticks": 10, "rows": 2, "lb_rows": {"reps": 2}})
+    three_tier = SimConfig(n_hosts=128, hosts_per_tor=16, tiers=3,
+                           tors_per_pod=2, aggs_per_pod=4, agg_uplinks=4)
+    ctx = harness.trace_context(three_tier, NC, window, "TPU v5 lite", 1)
+    with pytest.raises(NotImplementedError, match="2-tier"):
+        ctx["kernel_work"]()  # asked for by a roofline reader alone
+    ctx = harness.trace_context(CFG, NC, window, "TPU v5 lite", 1)
+    work = ctx["kernel_work"]()
+    assert work == kernel_work.bucket_tick(CFG, NC, {"reps": 2})
+    assert ctx["kernel_work"]() is work  # counted once
+    ctx["trace"] = types.SimpleNamespace(devices={
+        0: types.SimpleNamespace(kernel_by={"seg_rank": 2e6})})
+    want = kernel_work.least_time_s(work["seg_rank"] * 10, "TPU v5 lite")
+    assert kernel_work.roofline(ctx, "seg_rank") == pytest.approx(
+        100 * want / 2e-3)
