@@ -1326,11 +1326,12 @@ class Simulator:
             # adaptive switches exclude locally-known failed ports (link down is
             # visible at the switch); hashing LBs ignore q_len entirely.
             q_len_eff = q_len + failed_q.astype(jnp.int32) * jnp.int32(4 * QCAP)
-            target = topo.next_queue(
-                a_inj, a_cur, a_conn, a_ev, a_src, a_dst, q_len_eff,
-                adaptive=self.lb.switch_adaptive,
-            )
-            target = jnp.where(a_valid, target, NQ)
+            with jax.named_scope("route.next_hop"):
+                target = topo.next_queue(
+                    a_inj, a_cur, a_conn, a_ev, a_src, a_dst, q_len_eff,
+                    adaptive=self.lb.switch_adaptive,
+                )
+                target = jnp.where(a_valid, target, NQ)
             u_red = jax.random.uniform(jax.random.fold_in(key, 1), (self.MAX_ARR,))
 
             arrivals_backend = cfg.arrivals_backend

@@ -209,9 +209,7 @@ class Topology:
         in_agg_up = (cur_queue >= self.agg_up_base) & (
             cur_queue < self.core_down_base
         )
-        core = jnp.where(in_agg_up, cur_queue - self.agg_up_base, 0) % (
-            A * U2
-        )  # (p*A+a)*U2+u -> c = a*U2+u
+        # (p*A+a)*U2+u -> c = a*U2+u
         core = (jnp.where(in_agg_up, cur_queue - self.agg_up_base, 0) // U2 % A) * U2 + (
             jnp.where(in_agg_up, cur_queue - self.agg_up_base, 0) % U2
         )

@@ -8,7 +8,9 @@ both as called by the serial engine and batched over sweep rows by
 compile with its kernels as Mosaic custom calls: ``repro.kernels.ops``
 picks Mosaic from the platform the program is lowered for, so a program
 lowered for the described chip holds no interpreted kernel even though
-this process's default backend is the CPU.
+this process's default backend is the CPU.  The sweep's chunk program
+compiles on the 2-tier and the k = 8 3-tier fat tree, with the next hop
+(``route.next_hop``) in a fusion the benchmark's route reader finds.
 
 The topology is described inside a module fixture, not at import (one
 process may load the TPU library at a time), and the
@@ -16,6 +18,7 @@ persistent compilation cache is off for this module: entries compiled for
 a described chip could never be read back.
 """
 import os
+import pathlib
 import re
 
 import jax
@@ -25,6 +28,7 @@ from jax.sharding import SingleDeviceSharding
 
 from repro.kernels import ops
 
+ROOT = pathlib.Path(__file__).resolve().parents[1]
 ROWS = 2  # sweep rows for the vmapped compiles
 NH, NC = 128, 128  # FATTREE_128 hosts; permutation conns
 SCALE_NC = 100_000  # benchmarks/scale_smoke.py conns
@@ -188,3 +192,48 @@ def test_sweep_chunk_program_keeps_tick_scopes(sweep_chunk_text):
     assert {"tick.feedback", "tick.rto", "tick.service",
             "tick.injection"} <= kernels["seg_sum_pallas"]
     assert kernels["reps_tick_pallas"] == {"tick.lb"}
+
+
+def test_sweep_chunk_program_routes_in_one_fusion(sweep_chunk_text):
+    """The next-hop decision's scope (``route.next_hop``) reaches the ops'
+    metadata, and the fusion that computes it is mostly the route's: the
+    op whose time the benchmark's ``route.us_per_row_tick`` reads."""
+    from bench import extensions
+
+    reader = extensions.find(ROOT, "metrics", "route.us_per_row_tick")
+    assert "route.next_hop" in sweep_chunk_text
+    assert reader.routed_fusions(sweep_chunk_text)
+
+
+def test_three_tier_chunk_program_compiles(one_chip):
+    """The sweep's chunk program on the k = 8 3-tier fat tree (128 hosts,
+    640 queues, so the arrivals kernel takes 768 lanes) compiles for the
+    chip with its kernels as Mosaic custom calls and its next hop in one
+    fusion."""
+    from bench import extensions
+    from repro.netsim import SweepCase, SweepEngine, TelemetrySpec, workloads
+    from repro.netsim.config import SimConfig
+
+    cfg = SimConfig(n_hosts=NH, hosts_per_tor=4, tiers=3, tors_per_pod=4,
+                    aggs_per_pod=4, agg_uplinks=4, kernels_backend="pallas",
+                    arrivals_backend="pallas")
+    wl = workloads.permutation(NH, 16, seed=1)
+    cases = [SweepCase("p/reps", wl, "reps", 64, seeds=(0, 1), lb_kwargs=dict(
+        evs_size=cfg.evs_size, backend="pallas"))]
+    eng = SweepEngine(cfg, cases, devices=1, kernels_backend="pallas")
+    bucket, spec = eng.buckets[0], TelemetrySpec.default()
+    assert bucket.program.sim.NQ == 640
+    args = (
+        eng.bucket_carry(bucket, "summary", spec), bucket.keys, bucket.scn,
+        jnp.asarray(bucket.horizons), jnp.zeros((), jnp.int32),
+    )
+    fn = eng._make_chunk_fn(bucket.program, 8, "summary", spec)
+    text = fn.lower(*jax.tree_util.tree_map(
+        lambda x: _sds(one_chip, x.shape, x.dtype), args
+    )).compile().as_text()
+    mosaic = [line for line in text.splitlines() if "tpu_custom_call" in line]
+    for kernel in ("seg_rank_pallas", "seg_sum_pallas", "queue_tick_pallas",
+                   "reps_tick_pallas"):
+        assert any(kernel in line for line in mosaic), kernel
+    reader = extensions.find(ROOT, "metrics", "route.us_per_row_tick")
+    assert reader.routed_fusions(text)
